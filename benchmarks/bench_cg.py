@@ -24,9 +24,6 @@ Plus the Operator-era rows:
 """
 from __future__ import annotations
 
-import json
-import subprocess
-import sys
 import textwrap
 import time
 
@@ -42,7 +39,8 @@ from repro.sparse.generators import grid, rdg
 from repro.sparse.graph import laplacian_csr
 from repro.sparse.spmv import csr_to_padded_coo, spmv_coo
 
-from .common import row, write_bench_json as _write_bench_json
+from .common import cpu_rehearsal, row, run_child, \
+    write_bench_json as _write_bench_json
 
 DIST_SCRIPT = textwrap.dedent("""
     import os
@@ -408,17 +406,8 @@ def _bench_bottleneck(rows: list[str]) -> None:
     refinement must bring B and S_lvl below the cut-refined partition
     and the measured per-iteration time down with them."""
     t0 = time.perf_counter()
-    proc = subprocess.run([sys.executable, "-c", BOTTLENECK_SCRIPT],
-                          capture_output=True, text=True, timeout=1800)
+    out = run_child(["-c", BOTTLENECK_SCRIPT], timeout=1800)
     wall_s = time.perf_counter() - t0
-    if proc.returncode != 0:
-        rows.append(row("cg_bottleneck__ERROR", 0,
-                        proc.stderr[-200:].replace(",", ";")))
-        _write_bench_json("bottleneck", {
-            "bench": "bottleneck", "wall_s": wall_s,
-            "error": proc.stderr[-2000:]})
-        return
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
     cut, bn = out["cut"], out["bottleneck"]
     _write_bench_json("bottleneck", {
         "bench": "bottleneck", "wall_s": wall_s,
@@ -476,16 +465,8 @@ def _bench_tree(rows: list[str]) -> None:
     not the per-level-latency win the splits quantify.
     """
     t0 = time.perf_counter()
-    proc = subprocess.run([sys.executable, "-c", TREE_SCRIPT],
-                          capture_output=True, text=True, timeout=1200)
+    out = run_child(["-c", TREE_SCRIPT], timeout=1200)
     wall_s = time.perf_counter() - t0
-    if proc.returncode != 0:
-        rows.append(row("cg_tree__ERROR", 0,
-                        proc.stderr[-200:].replace(",", ";")))
-        _write_bench_json("tree", {"bench": "tree", "wall_s": wall_s,
-                                   "error": proc.stderr[-2000:]})
-        return
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
     _write_bench_json("tree", {
         "bench": "tree", "wall_s": wall_s,
         "rounds": {name: out[name]["rounds_by_level"]
@@ -534,16 +515,8 @@ def _bench_pod(rows: list[str]) -> None:
     not the slow-link win the volumes quantify.
     """
     t0 = time.perf_counter()
-    proc = subprocess.run([sys.executable, "-c", POD_SCRIPT],
-                          capture_output=True, text=True, timeout=1200)
+    out = run_child(["-c", POD_SCRIPT], timeout=1200)
     wall_s = time.perf_counter() - t0
-    if proc.returncode != 0:
-        rows.append(row("cg_pod__ERROR", 0,
-                        proc.stderr[-200:].replace(",", ";")))
-        _write_bench_json("pod", {"bench": "pod", "wall_s": wall_s,
-                                  "error": proc.stderr[-2000:]})
-        return
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
     _write_bench_json("pod", {
         "bench": "pod", "wall_s": wall_s,
         "rounds": {name: {"inter": out[name]["rounds_inter"],
@@ -590,16 +563,8 @@ def _bench_hier(rows: list[str]) -> None:
     show the schedule's overhead, not its win.
     """
     t0 = time.perf_counter()
-    proc = subprocess.run([sys.executable, "-c", HIER_SCRIPT],
-                          capture_output=True, text=True, timeout=1200)
+    out = run_child(["-c", HIER_SCRIPT], timeout=1200)
     wall_s = time.perf_counter() - t0
-    if proc.returncode != 0:
-        rows.append(row("cg_hier__ERROR", 0,
-                        proc.stderr[-200:].replace(",", ";")))
-        _write_bench_json("hier", {"bench": "hier", "wall_s": wall_s,
-                                   "error": proc.stderr[-2000:]})
-        return
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
     _write_bench_json("hier", {
         "bench": "hier", "wall_s": wall_s,
         "rounds": {"inter": out["rounds_inter"],
@@ -653,13 +618,7 @@ def _t(fn, *args):
 
 
 def _bench_operator_backends(rows: list[str]) -> None:
-    proc = subprocess.run([sys.executable, "-c", DIST_SCRIPT],
-                          capture_output=True, text=True, timeout=1200)
-    if proc.returncode != 0:
-        rows.append(row("cg_operator_backends__ERROR", 0,
-                        proc.stderr[-200:].replace(",", ";")))
-        return
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    out = run_child(["-c", DIST_SCRIPT], timeout=1200)
     for name in ("coo", "coo+jacobi", "bell", "dist_halo",
                  "dist_halo+jacobi", "dist_halo_seq", "dist_bell",
                  "dist_allgather"):
@@ -743,7 +702,8 @@ def main() -> None:
     only the multi-pod schedule section; ``--pod-aware``
     (``make bench-pod``): only the pod-aware vs pod-oblivious partition
     comparison; ``--tree`` (``make bench-tree``): the depth-3 (2, 2, 2)
-    per-level round/volume split.  All on forced host devices."""
+    per-level round/volume split.  All on forced host devices: a CPU
+    rehearsal (``common.cpu_rehearsal``), never a chip measurement."""
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--hier", action="store_true",
@@ -761,6 +721,7 @@ def main() -> None:
                          "tree runtime); the value picks the headline "
                          "row, both objectives always run")
     args = ap.parse_args()
+    cpu_rehearsal()
     print("name,us_per_call,derived")
     rows: list[str] = []
     if args.hier:

@@ -1,6 +1,6 @@
-"""Pallas kernel microbenchmarks (interpret mode on CPU — numbers reflect
-the reference execution; the structural roofline for TPU lives in
-EXPERIMENTS.md §Roofline)."""
+"""Pallas kernel microbenchmarks.  Each kernel takes its default path —
+interpreted on the CPU backend (numbers reflect the reference
+execution), compiled with Mosaic on a TPU."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -20,7 +20,7 @@ def run() -> list[str]:
     x = jnp.asarray(rng.normal(size=(4096, 3)), jnp.float32)
     c = jnp.asarray(rng.normal(size=(96, 3)), jnp.float32)
     us_p = time_us(lambda: pairwise_sqdist_pallas(
-        x, c, interpret=True).block_until_ready(), reps=3)
+        x, c).block_until_ready(), reps=3)
     us_r = time_us(lambda: pairwise_sqdist_ref(
         x, c).block_until_ready(), reps=3)
     rows.append(row("pdist_pallas_4096x96", us_p, f"ref_us={us_r:.0f}"))
@@ -34,13 +34,13 @@ def run() -> list[str]:
     xb = jnp.asarray(rng.normal(size=n), jnp.float32)
     bj, cj = jnp.asarray(blocks), jnp.asarray(cols)
     us_s = time_us(lambda: spmv_block_ell(
-        bj, cj, xb, interpret=True).block_until_ready(), reps=3)
+        bj, cj, xb).block_until_ready(), reps=3)
     rows.append(row("spmv_bell_2048", us_s,
                     f"nnzb={meta['nnzb']};fill={meta['fill']:.2f}"))
 
     q = jnp.asarray(rng.normal(size=(1, 4, 512, 64)), jnp.float32)
     us_f = time_us(lambda: flash_attention(
-        q, q, q, causal=True, interpret=True).block_until_ready(), reps=3)
+        q, q, q, causal=True).block_until_ready(), reps=3)
     us_fr = time_us(lambda: flash_attention_ref(
         q, q, q, causal=True).block_until_ready(), reps=3)
     rows.append(row("flash_attn_512", us_f, f"ref_us={us_fr:.0f}"))

@@ -18,23 +18,24 @@ Per backend (`make bench-serve`):
     ``nb x max(iters)`` (converged columns freeze instead of riding
     along).
 
+A CPU rehearsal: the process and its children run on JAX's CPU backend
+(``common.cpu_rehearsal``), so no number here is a chip measurement.
 Distributed backends run in a subprocess with 8 forced host devices
 (this process keeps the default 1); same caveat as bench_cg — host
-devices show schedule overhead, not interconnect wins.  Results land in
-CSV rows on stdout and ``benchmarks/baselines/BENCH_serve.json``.
+devices show schedule overhead, not interconnect wins.  A failed child
+exits the bench nonzero.  Results land in CSV rows on stdout and
+``benchmarks/baselines/BENCH_serve.json``.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
-from .common import row, write_bench_json
+from .common import cpu_rehearsal, row, run_child, write_bench_json
 
 WARM_REQUESTS = 12
 NB = 4
@@ -136,13 +137,8 @@ def _measure(backend: str) -> dict:
 def _subprocess_measure(backend: str) -> dict:
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_serve",
-         "--inner", backend],
-        capture_output=True, text=True, timeout=1200, env=env)
-    if proc.returncode != 0:
-        return {"error": proc.stderr[-2000:]}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return run_child(["-m", "benchmarks.bench_serve", "--inner", backend],
+                     timeout=1200, env=env)
 
 
 def main() -> None:
@@ -152,6 +148,7 @@ def main() -> None:
     ap.add_argument("--backends", default="coo,dist_halo,dist_hier",
                     help="comma-separated backends to bench")
     args = ap.parse_args()
+    cpu_rehearsal()
     if args.inner:
         print(json.dumps(_measure(args.inner)))
         return
@@ -164,10 +161,6 @@ def main() -> None:
         out = (_measure(backend) if backend == "coo"
                else _subprocess_measure(backend))
         payload["backends"][backend] = out
-        if "error" in out:
-            rows.append(row(f"serve_{backend}__ERROR", 0,
-                            out["error"][-200:].replace(",", ";")))
-            continue
         rows.append(row(f"serve_{backend}_cold", out["cold_ms"] * 1e3,
                         f"nb={out['nb']} n={out['n']}"))
         rows.append(row(

@@ -3,12 +3,45 @@ JSON baseline writer."""
 from __future__ import annotations
 
 import json
+import os
+import subprocess
 import sys
 import time
 from pathlib import Path
 from typing import Callable
 
 BASELINES = Path(__file__).resolve().parent / "baselines"
+
+
+def cpu_rehearsal() -> None:
+    """Pin this process, and through the environment every child it
+    starts, to JAX's CPU backend, and keep compiled programs in the
+    persistent compile cache.
+
+    The benches that force host devices are CPU rehearsals of the
+    solver schedules, never chip measurements: their timings say how
+    fast XLA's CPU backend is.  Call before the first JAX computation."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    jax.config.update("jax_platforms", "cpu")
+    use_compile_cache()
+
+
+def run_child(argv: list[str], timeout: int, env: dict | None = None) -> dict:
+    """Run one bench child (``python <argv>``) and return the JSON object
+    on the last line of its stdout.  A failed child fails the bench: its
+    stderr is echoed and the process exits nonzero — a failure is never
+    recorded as a result."""
+    proc = subprocess.run([sys.executable, *argv], capture_output=True,
+                          text=True, timeout=timeout, env=env)
+    if proc.returncode != 0:
+        sys.stderr.write(proc.stderr[-4000:])
+        raise SystemExit(f"bench child {argv[:2]} failed with exit code "
+                         f"{proc.returncode}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def write_bench_json(name: str, payload: dict) -> None:

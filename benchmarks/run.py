@@ -1,6 +1,8 @@
 """Benchmark harness — one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV.
+Prints ``name,us_per_call,derived`` CSV.  A CPU rehearsal: every bench
+runs on JAX's CPU backend (``common.cpu_rehearsal``), so no number here
+is a chip measurement.
 
   python -m benchmarks.run [--only block_sizes,partitioners,...]
 """
@@ -9,6 +11,8 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+
+from .common import cpu_rehearsal
 
 BENCHES = ("block_sizes", "hierarchical", "partitioners", "scaling",
            "cg", "kernels")
@@ -20,6 +24,7 @@ def main() -> None:
                     help=f"comma list from {BENCHES}")
     args = ap.parse_args()
     want = args.only.split(",") if args.only else BENCHES
+    cpu_rehearsal()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -7,10 +7,10 @@ an actual bugfix in the PR history:
   rule      what / why
   ========  ==============================================================
   REPRO001  ``jax.sharding`` / ``shard_map`` imported or referenced
-            outside ``compat.py``.  JAX moved ``shard_map`` and the
-            sharding API across 0.4.x; direct imports are the API-drift
-            class behind the PR 3 sharding-constraint no-op.  All access
-            goes through ``repro.compat``.
+            outside ``compat.py``.  JAX has moved ``shard_map`` and the
+            sharding API between releases; direct imports are the
+            API-drift class behind an earlier sharding-constraint
+            no-op.  All access goes through ``repro.compat``.
   REPRO002  blanket ``except Exception: pass`` (or bare ``except:``).
             Swallowing everything hid the PR 3 constraint no-op; catch
             the concrete types and record or re-raise.
@@ -29,9 +29,8 @@ an actual bugfix in the PR history:
             and that code is never jitted).
   ========  ==============================================================
 
-Pure ``ast`` — no imports of the linted code, so it runs identically on
-both CI matrix entries.  ``ALLOWLIST`` maps path suffixes to the rule
-codes permitted there (``compat.py`` is the single sanctioned home of
+Pure ``ast`` — no imports of the linted code.  ``ALLOWLIST`` maps path
+suffixes to the rule codes permitted there (``compat.py`` is the single sanctioned home of
 the sharding imports).
 """
 from __future__ import annotations
@@ -78,9 +77,10 @@ def _dotted(node: ast.AST) -> str:
 
 
 def _is_sharding_module(mod: str) -> bool:
-    return (mod == "jax.sharding" or mod.startswith("jax.sharding.")
-            or mod == "jax.experimental.shard_map"
-            or mod.startswith("jax.experimental.shard_map."))
+    """``jax.sharding[.*]`` or any ``jax.*.shard_map[.*]`` module."""
+    parts = mod.split(".")
+    return parts[0] == "jax" and (parts[1:2] == ["sharding"]
+                                  or "shard_map" in parts[1:])
 
 
 def _is_jit_decorator(dec: ast.AST) -> bool:

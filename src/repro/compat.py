@@ -1,61 +1,47 @@
-"""JAX version compatibility shims.
+"""The repo's one import path for JAX's sharding API.
 
-Compat policy
--------------
-The repo targets the *current* JAX API surface (``jax.shard_map``,
-``jax.sharding.use_mesh`` / ``set_mesh``, ``jax.sharding.get_abstract_mesh``)
-but must keep running on the previous generation (0.4.x), where
+The repo targets a single JAX, the installed 0.9 line, on the CPU (tests)
+and on TPU v5e.  Every call site goes through this module instead of
+touching the sharding namespace directly.  Rules for new code:
 
-  * ``shard_map`` lives in ``jax.experimental.shard_map``;
-  * there is no ``set_mesh`` / ``use_mesh`` — the ambient mesh is the
-    thread-resident *physical* mesh set by ``with mesh:``;
-  * there is no ``get_abstract_mesh`` — the ambient mesh is read from
-    ``jax.interpreters.pxla.thread_resources``.
-
-Every call site in this repo goes through this module instead of touching
-the moving pieces directly.  Rules for new code:
-
-  1. Never call ``jax.sharding.set_mesh`` / ``use_mesh`` directly — use
-     :func:`use_mesh` (a context manager on every version).
-  2. Never call ``jax.shard_map`` / ``jax.experimental.shard_map.shard_map``
-     directly — use :func:`shard_map`.
+  1. Never call ``jax.set_mesh`` directly — use :func:`use_mesh`.
+  2. Never call ``jax.shard_map`` directly — use :func:`shard_map`.
   3. Never call ``jax.sharding.get_abstract_mesh`` directly — use
      :func:`get_ambient_mesh` (returns ``None`` when no mesh is ambient).
-  4. Never import from ``jax.sharding`` at all outside this module — the
-     stable names (``Mesh``, ``PartitionSpec``/``P``, ``NamedSharding``)
-     are re-exported here so every sharding symbol has one import path.
-     Lint rule REPRO001 (``repro.analysis.lint``) enforces this; this
-     module is the single allowlisted file.
-
-The shims are resolved once at import time; there is no per-call overhead
-beyond one extra Python frame.
+  4. Build concrete meshes with :func:`make_mesh` (``Auto`` axes: sharding
+     constraints and replicated ops keep their meaning without explicit
+     ``out_sharding`` arguments).
+  5. Never import from ``jax.sharding`` at all outside this module — the
+     names the repo needs (``Mesh``, ``PartitionSpec``/``P``,
+     ``NamedSharding``) are re-exported here.  Lint rule REPRO001
+     (``repro.analysis.lint``) enforces this; this module is the single
+     allowlisted file.
 """
 from __future__ import annotations
 
 import contextlib
-import inspect
-from typing import Any, Callable
+from typing import Any, Callable, Sequence
 
 import jax
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-# Stable re-exports: these classes have kept their names across the
-# supported versions, but importing them from one place keeps the rest of
-# the tree free of `jax.sharding` (REPRO001) so the next rename lands here.
 Mesh = jax.sharding.Mesh
 PartitionSpec = jax.sharding.PartitionSpec
 P = PartitionSpec
 NamedSharding = jax.sharding.NamedSharding
 
-# AbstractMesh: a mesh that carries axis names/sizes but no devices, so
-# shard_map programs can be traced (jax.make_jaxpr / eval_shape) on a
-# machine with none of the target topology.  The constructor changed
-# shape across releases: 0.4.x/0.5.x take a shape tuple of (name, size)
-# pairs, current JAX takes (axis_sizes, axis_names).
-_AbstractMesh = getattr(jax.sharding, "AbstractMesh", None)
-HAS_ABSTRACT_MESH: bool = _AbstractMesh is not None
+
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
+              devices=None) -> Mesh:
+    """Concrete mesh over ``devices`` (default: the first ``prod(shape)``
+    of ``jax.devices()``) with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` asserts instead of constraining and ops
+    such as ``jnp.repeat`` demand an ``out_sharding``; the repo's programs
+    are written for ``Auto`` axes."""
+    shape, names = tuple(int(s) for s in shape), tuple(axis_names)
+    auto = (jax.sharding.AxisType.Auto,) * len(names)
+    return jax.make_mesh(shape, names, axis_types=auto, devices=devices)
 
 
 def abstract_mesh(shape) -> Any:
@@ -66,199 +52,55 @@ def abstract_mesh(shape) -> Any:
     be abstractly traced for the jaxpr-level audit
     (``repro.analysis.trace``) without any devices.
     """
-    if _AbstractMesh is None:
-        raise NotImplementedError(
-            "jax.sharding.AbstractMesh is unavailable on this JAX version; "
-            "device-free tracing needs jax >= 0.4.34")
     pairs = tuple(shape.items()) if hasattr(shape, "items") else tuple(shape)
-    try:
-        return _AbstractMesh(pairs)
-    except TypeError:
-        return _AbstractMesh(tuple(s for _, s in pairs),
-                             tuple(n for n, _ in pairs))
-
-
-# Partial-manual shard_map (manual over a subset of mesh axes) only works
-# where it is a first-class API (jax.shard_map with axis_names); the 0.4.x
-# `auto=` spelling trips an XLA CHECK (IsManualSubgroup) when lowered under
-# jit.  Call sites that *optionally* go partial-manual gate on this flag.
-SUPPORTS_PARTIAL_MANUAL: bool = hasattr(jax, "shard_map")
-
-
-# --------------------------------------------------------------------------
-# shard_map
-# --------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                               # jax >= 0.6
-    _shard_map_impl = jax.shard_map
-else:                                                       # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# the replication-check kwarg was renamed check_rep -> check_vma upstream;
-# resolve the name once here so call-time errors surface undisturbed
-try:
-    _SM_PARAMS = frozenset(
-        inspect.signature(_shard_map_impl).parameters)
-except (TypeError, ValueError):                             # C-level callable
-    _SM_PARAMS = frozenset()
-_CHECK_KW = ("check_rep" if "check_rep" in _SM_PARAMS
-             else "check_vma" if "check_vma" in _SM_PARAMS else None)
+    return jax.sharding.AbstractMesh(tuple(s for _, s in pairs),
+                                     tuple(n for n, _ in pairs))
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_rep: bool = False,
               axis_names: frozenset | set | None = None) -> Callable:
-    """Version-stable ``shard_map``.
+    """``jax.shard_map`` with the replication check off by default.
 
-    ``check_rep`` (renamed ``check_vma`` upstream) defaults to False: the
+    ``check_rep`` (``check_vma`` upstream) defaults to False: the
     halo-exchange programs in ``sparse.distributed`` use ``ppermute``,
-    whose replication rules differ across versions.
-
-    ``axis_names`` is the current partial-manual spelling (the set of mesh
-    axes the body is *manual* over); on 0.4.x it is translated to the
-    complementary ``auto=`` frozenset.
-    """
+    whose outputs the check cannot type.  ``axis_names`` is the set of
+    mesh axes the body is *manual* over (partial-manual when it is a
+    strict subset)."""
     kwargs: dict[str, Any] = {}
     if axis_names is not None:
-        if not SUPPORTS_PARTIAL_MANUAL:
-            # the 0.4.x `auto=` spelling of partial-manual is a known hard
-            # XLA CHECK crash under jit (see SUPPORTS_PARTIAL_MANUAL above)
-            # — fail loudly in Python instead of aborting the process
-            raise NotImplementedError(
-                "partial-manual shard_map (axis_names=...) is not supported "
-                "on this JAX version; gate on compat.SUPPORTS_PARTIAL_MANUAL "
-                "and fall back to a fully-manual program")
         kwargs["axis_names"] = set(axis_names)
-    if _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check_rep
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-# --------------------------------------------------------------------------
-# manual-region detection + sharding constraints
-# --------------------------------------------------------------------------
-
-def _manual_axes_from_abstract_mesh() -> set:
-    """Axis names the ambient *abstract* mesh marks Manual (current JAX).
-
-    Inside a ``shard_map`` body on current JAX the ambient abstract mesh
-    carries per-axis types; Manual axes are exactly the ones the body is
-    manual over.  ``axis_types`` has been both a tuple (one entry per axis)
-    and a dict (type -> names) across releases — handle either shape.
-    """
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abs is None:
-        return set()
-    try:
-        mesh = get_abs()
-    except Exception:
-        return set()
-    axis_types = getattr(mesh, "axis_types", None)
-    if mesh is None or axis_types is None:
-        return set()
-    names = tuple(getattr(mesh, "axis_names", ()))
-    out: set = set()
-    if isinstance(axis_types, dict):                        # type -> name(s)
-        for t, ax in axis_types.items():
-            if "anual" in str(t):
-                out.update(ax if isinstance(ax, (tuple, list, set, frozenset))
-                           else (ax,))
-    else:                                                   # tuple per axis
-        for name, t in zip(names, tuple(axis_types)):
-            if "anual" in str(t):
-                out.add(name)
-    return out
-
-
-def _bound_axis_names() -> set:
-    """Axis names bound in the current trace's axis env (0.4.x/0.5.x).
-
-    Inside a fully-manual ``shard_map`` body the mesh axes are bound as
-    named axes (same mechanism as ``psum`` resolution), so this detects
-    manual regions on versions without abstract-mesh axis types.  (vmap
-    ``axis_name=`` also binds names — callers intersect with the mesh's
-    axis names, and constraining over a vmapped axis name would be just as
-    illegal, so the over-approximation is safe.)
-    """
-    fn = getattr(jax.core, "unsafe_get_axis_names_DO_NOT_USE", None)
-    if fn is None:
-        return set()
-    try:
-        return set(fn())
-    except Exception:
-        return set()
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep, **kwargs)
 
 
 def manual_axis_names() -> frozenset:
     """Mesh axis names the *current trace* is manual over.
 
     Empty outside ``shard_map``; inside a (fully or partially) manual
-    region it contains the manual axes, on every supported JAX version.
-    Used by ``models.common.maybe_constrain`` to drop manual axes from
-    sharding constraints (constraining over a manual axis is an error).
+    region the ambient abstract mesh types those axes ``Manual``.  Used by
+    ``models.common.maybe_constrain`` to drop manual axes from sharding
+    constraints (constraining over a manual axis is an error).
     """
-    return frozenset(_manual_axes_from_abstract_mesh() | _bound_axis_names())
+    mesh = jax.sharding.get_abstract_mesh()
+    return frozenset(
+        name for name, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == jax.sharding.AxisType.Manual)
 
-
-def constrain_to_mesh(x, mesh, spec):
-    """``with_sharding_constraint`` against an ambient mesh of either kind.
-
-    A concrete ``Mesh`` (the 0.4.x ``with mesh:`` ambient) needs the spec
-    wrapped in a ``NamedSharding``; the current-JAX abstract ambient mesh
-    accepts the bare ``PartitionSpec``.  Deliberately *not* wrapped in a
-    try/except: spec errors (rank mismatch, unknown axis) must surface.
-    """
-    if isinstance(mesh, jax.sharding.Mesh):
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.NamedSharding(mesh, spec))
-    return jax.lax.with_sharding_constraint(x, spec)
-
-
-# --------------------------------------------------------------------------
-# ambient mesh
-# --------------------------------------------------------------------------
 
 def use_mesh(mesh) -> contextlib.AbstractContextManager:
-    """Context manager making ``mesh`` ambient for sharding decisions.
-
-    Prefers ``jax.sharding.use_mesh`` / ``set_mesh`` (current API); falls
-    back to the legacy global-mesh context (``with mesh:``) on 0.4.x.
-    """
-    for name in ("use_mesh", "set_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn(mesh)
-    return mesh                                  # Mesh.__enter__ (legacy)
+    """Context manager making ``mesh`` ambient for sharding decisions."""
+    return jax.set_mesh(mesh)
 
 
 def get_ambient_mesh() -> Any | None:
-    """The ambient mesh set by :func:`use_mesh`, or ``None``.
-
-    On current JAX this is the abstract mesh; on 0.4.x it is the concrete
-    thread-resident physical mesh.  Either carries ``axis_names`` /
-    ``shape`` and is accepted by :func:`shard_map`.
-    """
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abs is not None:
-        try:
-            mesh = get_abs()
-        except Exception:
-            return None
-        if mesh is None or not getattr(mesh, "axis_names", ()):
-            return None
-        return mesh
-    try:
-        from jax.interpreters.pxla import thread_resources
-        mesh = thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or getattr(mesh, "empty", False):
-        return None
-    return mesh
+    """The abstract mesh made ambient by :func:`use_mesh`, or ``None``.
+    It carries ``axis_names`` / ``shape`` and is accepted by
+    :func:`shard_map`."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
-__all__ = ["JAX_VERSION", "Mesh", "PartitionSpec", "P", "NamedSharding",
+__all__ = ["Mesh", "PartitionSpec", "P", "NamedSharding", "make_mesh",
            "shard_map", "use_mesh", "get_ambient_mesh",
-           "manual_axis_names", "constrain_to_mesh",
-           "abstract_mesh", "HAS_ABSTRACT_MESH"]
+           "manual_axis_names", "abstract_mesh"]

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import default_interpret
+
 _NEG = -1e30
 
 
@@ -72,8 +74,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
-    """q, k, v: (B, H, S, D) -> (B, H, S, D).  Softmax scale 1/sqrt(D)."""
+                    bk: int = 128, interpret: bool | None = None):
+    """q, k, v: (B, H, S, D) -> (B, H, S, D).  Softmax scale 1/sqrt(D).
+    ``interpret=None`` resolves via :func:`repro.kernels.default_interpret`."""
+    if interpret is None:
+        interpret = default_interpret()
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     scale = D ** -0.5

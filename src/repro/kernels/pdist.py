@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import default_interpret
+
 
 def _pdist_kernel(x_ref, c_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)          # (BN, D)
@@ -29,11 +31,14 @@ def _pdist_kernel(x_ref, c_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
 def pairwise_sqdist_pallas(x: jnp.ndarray, c: jnp.ndarray, bn: int = 256,
-                           bk: int = 128, interpret: bool = True):
+                           bk: int = 128, interpret: bool | None = None):
     """(n, d) x (k, d) -> (n, k) squared distances.
 
-    interpret=True on CPU (this container); False on real TPU.
+    ``interpret=None`` resolves via :func:`repro.kernels.default_interpret`
+    (interpreted on the CPU backend, compiled Mosaic otherwise).
     """
+    if interpret is None:
+        interpret = default_interpret()
     n, d = x.shape
     k, _ = c.shape
     # pad: lanes want multiples of 128 in the minor dim, sublanes 8.

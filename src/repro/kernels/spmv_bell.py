@@ -16,17 +16,20 @@ The kernel walks grid (stripes, NNZB); the x panel for each step is selected
 with a data-dependent BlockSpec index_map fed by scalar prefetch
 (PrefetchScalarGridSpec), so the right (BK,) slice of x is already in VMEM
 when the MXU needs it.  Output accumulates across the NNZB grid dimension.
+The stripes run in chunks, one pallas_call each, so that every call's
+prefetched column table fits in SMEM.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import default_interpret
 
 
 # --------------------------------------------------------------------------
@@ -141,15 +144,11 @@ def padded_coo_to_block_ell(rows: np.ndarray, cols: np.ndarray,
 # Kernel
 # --------------------------------------------------------------------------
 
-def default_interpret() -> bool:
-    """Backend detection for the kernel path: the block-ELL kernel uses
-    TPU-only Pallas features (PrefetchScalarGridSpec), so it compiles for
-    real on TPU and falls back to the Pallas interpreter elsewhere (CPU
-    dry-runs, CI).  ``REPRO_PALLAS_INTERPRET=0/1`` overrides detection."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    return jax.default_backend() != "tpu"
+# The scalar-prefetched column table lives in SMEM (1 MiB on a TPU v5e),
+# flat (a 2-D (S, NNZB) table pads NNZB to 128 lanes).  Each pallas_call
+# takes a chunk of stripes whose flat table is at most this many int32
+# entries (128 KiB), so any stripe count fits.
+SMEM_TABLE_ENTRIES = 1 << 15
 
 
 def spmv_block_ell(blocks: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
@@ -158,8 +157,9 @@ def spmv_block_ell(blocks: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
     blocks' dtype (the kernel computes in the blocks' dtype — float64
     blocks keep float64 accumulation under the interpreter/CPU path).
 
-    ``interpret=None`` resolves via :func:`default_interpret` — compiled
-    Mosaic on TPU, interpreter elsewhere."""
+    ``interpret=None`` resolves via :func:`repro.kernels.default_interpret`
+    — the Pallas interpreter on the CPU backend, compiled Mosaic
+    otherwise."""
     if interpret is None:
         interpret = default_interpret()
     return _spmv_block_ell(blocks, cols, x, interpret)
@@ -172,36 +172,49 @@ def _spmv_block_ell(blocks: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
     dt = blocks.dtype
     n = x.shape[0]
     P = -(-n // BK)
-    xp = jnp.zeros((P, BK), dt).at[
-        jnp.arange(n) // BK, jnp.arange(n) % BK].set(x.astype(dt))
+    # x as (P, 1, BK) and y as (S, 1, BM): the last two block dims then
+    # equal the array's (Mosaic's tiling rule for blocks below (8, 128))
+    xp = jnp.pad(x.astype(dt), (0, P * BK - n)).reshape(P, 1, BK)
+    flat = cols.reshape(-1)
+    chunk = max(SMEM_TABLE_ENTRIES // NNZB, 1)
+    ys = []
+    for s0 in range(0, S, chunk):
+        m = min(chunk, S - s0)
+        ys.append(_stripe_chunk(blocks, flat[s0 * NNZB:(s0 + m) * NNZB], xp,
+                                s0, m, interpret))
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    return y.reshape(-1)[:n]
 
+
+def _stripe_chunk(blocks, cols_flat, xp, s0: int, m: int, interpret: bool):
+    """Stripes ``[s0, s0 + m)`` of the product, (m, 1, BM)."""
+    _, NNZB, BM, BK = blocks.shape
+    dt = blocks.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(S, NNZB),
+        grid=(m, NNZB),
         in_specs=[
-            pl.BlockSpec((1, 1, BM, BK), lambda s, b, cols: (s, b, 0, 0)),
-            pl.BlockSpec((1, BK), lambda s, b, cols: (cols[s, b], 0)),
+            pl.BlockSpec((1, 1, BM, BK),
+                         lambda s, b, cols: (s0 + s, b, 0, 0)),
+            pl.BlockSpec((1, 1, BK),
+                         lambda s, b, cols: (cols[s * NNZB + b], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, BM), lambda s, b, cols: (s, 0)),
+        out_specs=pl.BlockSpec((1, 1, BM), lambda s, b, cols: (s, 0, 0)),
     )
 
     def kernel(cols_ref, blocks_ref, x_ref, y_ref):
-        b = pl.program_id(1)
-
-        @pl.when(b == 0)
+        @pl.when(pl.program_id(1) == 0)
         def _init():
             y_ref[...] = jnp.zeros_like(y_ref)
 
-        a = blocks_ref[0, 0]                  # (BM, BK)
-        xv = x_ref[...]                       # (1, BK)
-        y_ref[...] += jax.lax.dot_general(
-            xv, a, (((1,), (1,)), ((), ())),
-            preferred_element_type=dt)        # (1, BM)
+        y_ref[0] += jax.lax.dot_general(
+            x_ref[0], blocks_ref[0, 0], (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=dt)        # (1, BK) x (BM, BK)^T
 
-    y = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, BM), dt),
+        out_shape=jax.ShapeDtypeStruct((m, 1, BM), dt),
         interpret=interpret,
-    )(cols, blocks, xp)
-    return y.reshape(-1)[:n]
+    )(cols_flat, blocks, xp)

@@ -303,12 +303,13 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
         rec.update(roofline_terms(ext["hlo_flops"], ext["hlo_bytes"],
                                   ext["collectives"]))
         if "hlo_bytes_structural" in ext:
-            from .mesh import HW
+            from .mesh import peaks
+            hbm_bw = peaks()["hbm_bw"]
             rec["memory_s_structural"] = (ext["hlo_bytes_structural"]
-                                          / HW["hbm_bw"])
+                                          / hbm_bw)
             rec["memory_s_structural_flash"] = (
                 (ext["hlo_bytes_structural"]
-                 - ext.get("hlo_bytes_attn_s2", 0.0)) / HW["hbm_bw"])
+                 - ext.get("hlo_bytes_attn_s2", 0.0)) / hbm_bw)
         rec["cost_pass_s"] = round(time.time() - t0, 2)
 
     rec["accum_steps"] = accum

@@ -9,7 +9,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from ..compat import Mesh
+from ..compat import make_mesh
 
 
 def tree_axis_names(h: int) -> tuple[str, ...]:
@@ -37,10 +37,10 @@ def make_production_mesh(*, multi_pod: bool = False, fanouts=None):
         fanouts = tuple(int(f) for f in fanouts)
         axes = (("pod", "data", "model") if len(fanouts) == 3
                 else tree_axis_names(len(fanouts)))
-        return jax.make_mesh(fanouts, axes)
+        return make_mesh(fanouts, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(k: int = 8, axes: tuple[str, ...] = ("data",),
@@ -64,27 +64,42 @@ def make_test_mesh(k: int = 8, axes: tuple[str, ...] = ("data",),
         fanouts = tuple(int(f) for f in fanouts)
         if int(np.prod(fanouts)) != k:
             raise ValueError(f"prod(fanouts)={np.prod(fanouts)} != k={k}")
-        return Mesh(np.array(devs).reshape(fanouts),
-                                 tree_axis_names(len(fanouts)))
+        return make_mesh(fanouts, tree_axis_names(len(fanouts)), devs)
     if pods is not None:
         if axes != ("data",):
             raise ValueError("pods= fixes the axes to ('pod', 'pu'); "
                              f"drop axes={axes!r}")
         if pods <= 0 or k % pods:
             raise ValueError(f"pods={pods} must divide k={k}")
-        return Mesh(np.array(devs).reshape(pods, k // pods),
-                                 ("pod", "pu"))
-    shape = (k,) if len(axes) == 1 else None
-    return Mesh(np.array(devs).reshape(
-        shape or (k // 2, 2)), axes)
+        return make_mesh((pods, k // pods), ("pod", "pu"), devs)
+    shape = (k,) if len(axes) == 1 else (k // 2, 2)
+    return make_mesh(shape, axes, devs)
 
 
-# TPU v5e-class hardware constants (per chip) for the roofline analysis.
-HW = dict(
-    peak_flops=197e12,      # bf16 FLOP/s
-    hbm_bw=819e9,           # B/s
-    link_bw=50e9,           # B/s per ICI link
-)
+# Published per-chip peaks for the roofline analysis, keyed by
+# ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e" (per-chip specifications).
+PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": dict(
+        peak_flops=197e12,      # bf16 FLOP/s
+        hbm_bw=819e9,           # HBM B/s
+        hbm_bytes=16e9,         # HBM capacity
+        ici_bw=1600e9 / 8,      # chip-to-chip interconnect: 1,600 Gbit/s
+    ),
+}
+
+# the chip this repo's static rooflines price against (a TPU v5e)
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str = TARGET_KIND) -> dict[str, float]:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 # Deployment flags for real TPU pods: compute/communication overlap is
 # XLA's latency-hiding scheduler — the collective schedule this framework

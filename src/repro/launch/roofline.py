@@ -2,7 +2,10 @@
 
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s
   memory term     = HLO_bytes_per_device / HBM_bw
-  collective term = collective_bytes_per_device / link_bw
+  collective term = collective_bytes_per_device / ICI_bw
+
+with the per-chip peaks of ``launch.mesh.PEAKS`` for the chip's
+``device_kind``.
 
 cost_analysis() of the SPMD-partitioned executable reports the *per-device*
 program, so dividing by per-chip peaks gives the same number as the global
@@ -20,7 +23,7 @@ from typing import Any
 
 import numpy as np
 
-from .mesh import HW
+from .mesh import TARGET_KIND, peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -165,13 +168,16 @@ def structural_bytes(hlo_text: str,
 
 
 def roofline_terms(flops: float, bytes_accessed: float,
-                   coll: dict[str, int]) -> dict[str, Any]:
-    """Three per-device roofline terms in seconds + the dominant one."""
+                   coll: dict[str, int],
+                   device_kind: str = TARGET_KIND) -> dict[str, Any]:
+    """Three per-device roofline terms in seconds + the dominant one,
+    against the peaks of one ``device_kind`` chip."""
+    pk = peaks(device_kind)
     comm_bytes = sum(v * (2 if k == "all-reduce" else 1)
                      for k, v in coll.items())
-    t_compute = flops / HW["peak_flops"]
-    t_memory = bytes_accessed / HW["hbm_bw"]
-    t_coll = comm_bytes / HW["link_bw"]
+    t_compute = flops / pk["peak_flops"]
+    t_memory = bytes_accessed / pk["hbm_bw"]
+    t_coll = comm_bytes / pk["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dom = max(terms, key=terms.get)
@@ -185,7 +191,7 @@ def roofline_terms(flops: float, bytes_accessed: float,
     }
 
 
-def static_roofline(cost) -> dict[str, Any]:
+def static_roofline(cost, device_kind: str = TARGET_KIND) -> dict[str, Any]:
     """Roofline terms from a static ``analysis.trace.TraceCost`` — the
     device-free counterpart of :func:`analyze_compiled`: no compilation,
     no HLO, just the jaxpr-counted per-CG-iteration FLOPs/bytes.
@@ -194,12 +200,13 @@ def static_roofline(cost) -> dict[str, Any]:
     roofline terms are per-device, so everything is divided by
     ``n_devices`` first.  ``cost.collectives()`` already uses the HLO
     collective names :func:`roofline_terms` expects (psum bytes arrive
-    once and get the all-reduce x2 there).
+    once and get the all-reduce x2 there).  Raises ``ValueError`` for a
+    ``device_kind`` with no published peaks.
     """
     k = max(int(cost.n_devices), 1)
     coll = {name: b / k for name, b in cost.collectives().items()}
     out = roofline_terms(cost.flops_per_iter / k,
-                         cost.hbm_bytes_per_iter / k, coll)
+                         cost.hbm_bytes_per_iter / k, coll, device_kind)
     out["static_flops_per_iter"] = cost.flops_per_iter
     out["static_bytes_per_iter"] = cost.hbm_bytes_per_iter
     out["n_devices"] = k
@@ -262,8 +269,9 @@ def analyze_compiled(lowered, compiled,
     sb, s2b = structural_bytes(hlo, s2_dim=seq_len)
     out["hlo_bytes_structural"] = sb
     out["hlo_bytes_attn_s2"] = s2b
-    out["memory_s_structural"] = sb / HW["hbm_bw"]
-    out["memory_s_structural_flash"] = (sb - s2b) / HW["hbm_bw"]
+    hbm_bw = peaks()["hbm_bw"]
+    out["memory_s_structural"] = sb / hbm_bw
+    out["memory_s_structural_flash"] = (sb - s2b) / hbm_bw
     try:
         mem = compiled.memory_analysis()
         out["memory"] = {

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import hashlib
 import time
 from collections import OrderedDict
@@ -133,9 +134,10 @@ class SolverService:
     verbatim (e.g. ``backend='dist_hier', part=..., k=8, mesh=...,
     pods=2``), so one service class fronts every SpMV backend; the
     solver parameters are fixed per service (one compiled program per
-    matrix x size class).  ``capacity`` bounds the operator cache
-    (least-recently-used eviction drops the operator *and all its
-    compiled solves*)."""
+    operand shapes x size class; the matrix is an operand of the program,
+    not a constant in it).  ``capacity`` bounds the operator cache
+    (least-recently-used eviction drops the operator and its device
+    arrays)."""
 
     def __init__(self, backend: str = "coo",
                  buckets: tuple[int, ...] = (1, 2, 4, 8, 16),
@@ -158,11 +160,14 @@ class SolverService:
         self.stats = ServeStats()
         self._ops: OrderedDict[str, object] = OrderedDict()
         self._warm: set[tuple[str, int]] = set()
-        # fingerprint -> jitted batched cg_solve for operators without a
-        # fused .solve (the single-device backends): without this every
-        # warm request would re-trace the while_loop body, and the cache
-        # hit would only skip format conversion, not compilation
-        self._jit: dict[str, object] = {}
+        # one jitted batched CG for the operators without a fused .solve
+        # (the single-device backends): the operator is an argument (a
+        # pytree of its device arrays), so its matrix is never compiled
+        # into the program, and one program serves every matrix and
+        # request of the same shapes
+        self._solve = jax.jit(functools.partial(
+            cg_solve, tol=tol, max_iters=max_iters,
+            precondition=precondition, batched=True))
         # (fingerprint, bucket) -> static price (trace audit + roofline)
         self._cost: dict[tuple[str, int], dict] = {}
         # streaming updates (update_matrix): host CSR per cached matrix,
@@ -211,10 +216,9 @@ class SolverService:
             self.stats.operator_evictions += 1
 
     def _retire(self, fp: str) -> None:
-        """Drop every per-matrix cache keyed by ``fp`` — compiled solves,
-        warm size classes, static prices, host CSR, drift state."""
+        """Drop every per-matrix cache keyed by ``fp`` — warm size
+        classes, static prices, host CSR, drift state."""
         self._warm = {w for w in self._warm if w[0] != fp}
-        self._jit.pop(fp, None)
         self._cost = {key: v for key, v in self._cost.items()
                       if key[0] != fp}
         self._csr.pop(fp, None)
@@ -380,7 +384,7 @@ class SolverService:
         if bucket > nb:
             pad = np.zeros((bcols.shape[0], bucket - nb), bcols.dtype)
             bcols = np.concatenate([bcols, pad], axis=1)
-        res = self._run(fp, op, bcols)
+        res = self._run(op, bcols)
         x = op.gather(res.x)[:, :nb]
         iters = np.asarray(res.iters)[:nb]
         residual = np.asarray(res.residual)[:nb]
@@ -390,18 +394,12 @@ class SolverService:
                              fingerprint=fp, bucket=bucket, cache_hit=hit,
                              warm=warm)
 
-    def _run(self, fp, op, bcols) -> CGResult:
+    def _run(self, op, bcols) -> CGResult:
         if hasattr(op, "solve"):        # fused distributed program (its
             # own per-(tol, max_iters, precondition) trace cache)
             return op.solve(bcols, tol=self.tol, max_iters=self.max_iters,
                             precondition=self.precondition)
-        fn = self._jit.get(fp)
-        if fn is None:
-            fn = jax.jit(lambda b: cg_solve(
-                op, b, tol=self.tol, max_iters=self.max_iters,
-                precondition=self.precondition, batched=True))
-            self._jit[fp] = fn          # retraces once per size class
-        return fn(op.scatter(bcols))
+        return self._solve(op, op.scatter(bcols))
 
 
 def _solver_traffic(args) -> None:
@@ -531,6 +529,8 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.8)
     args = ap.parse_args()
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     if args.solver:
         _solver_traffic(args)
     else:

@@ -115,9 +115,7 @@ class ParamCollector:
 
 def maybe_constrain(x: jnp.ndarray, axes: tuple[str | None, ...]):
     """with_sharding_constraint via logical axis names against the ambient
-    mesh (``compat.get_ambient_mesh`` — works on 0.4.x, where the previous
-    ``jax.sharding.get_abstract_mesh`` spelling silently no-op'd and dryrun
-    cells lowered without internal constraints).
+    mesh (``compat.get_ambient_mesh``).
 
     No-op when no mesh is ambient (single-device tests).  Inside
     ``shard_map`` *manual* regions, constraining over a manual axis is an
@@ -128,8 +126,7 @@ def maybe_constrain(x: jnp.ndarray, axes: tuple[str | None, ...]):
     over the remaining auto axes.  Genuine spec errors (rank mismatch,
     unknown mesh axis) are deliberately *not* swallowed.
     """
-    from ..compat import constrain_to_mesh, get_ambient_mesh, \
-        manual_axis_names
+    from ..compat import get_ambient_mesh, manual_axis_names
 
     mesh = get_ambient_mesh()
     if mesh is None:
@@ -142,7 +139,7 @@ def maybe_constrain(x: jnp.ndarray, axes: tuple[str | None, ...]):
     if not avail:
         return x                       # fully-manual shard_map region
     spec = logical_to_spec(axes, mesh_axes=avail)
-    return constrain_to_mesh(x, mesh, spec)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # -- norms --------------------------------------------------------------------

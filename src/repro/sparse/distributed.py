@@ -49,7 +49,8 @@ Four exchange strategies are provided:
 
 Orthogonally, ``local_format`` selects the interior matvec kernel:
 padded-COO scatter-add (``'coo'``) or the Pallas block-ELL kernel of
-kernels/spmv_bell.py (``'bell'``, TPU-compiled, interpreted elsewhere).
+kernels/spmv_bell.py (``'bell'``, TPU-compiled, interpreted on the CPU
+backend).
 
 Plan construction (:func:`build_plan`) is fully vectorized NumPy —
 ``searchsorted`` / ``unique`` / fancy-index scatter; the only Python loops
@@ -71,7 +72,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..compat import Mesh, P, shard_map
+from ..compat import Mesh, NamedSharding, P, shard_map
 from ..core.refinement import vizing_edge_coloring
 from .cg import cg_solve, jacobi_preconditioner
 
@@ -1421,10 +1422,39 @@ def _local_matvec_builder(plan: DistPlan, comm: str, axis: str,
     return (blocks, bcols) + bnd + tail, fn
 
 
+def place_blocks(arrays, mesh, axis: str | tuple = "pu"):
+    """A pytree of (k, ...) arrays (host or device) placed block ``b`` on
+    mesh position ``b`` — a no-op for arrays already placed so.  A
+    device-free ``AbstractMesh`` holds no arrays: they go to the default
+    device."""
+    if not isinstance(mesh, Mesh):
+        return jax.tree.map(jnp.asarray, arrays)
+    spec = P(axis if isinstance(axis, str) else tuple(axis))
+    return jax.device_put(arrays, NamedSharding(mesh, spec))
+
+
+def shard_plan(plan: DistPlan, mesh, axis: str | tuple = "pu") -> DistPlan:
+    """``plan`` with every device array (all carry the leading block
+    axis) placed one block per device of ``mesh``, so no device holds
+    another's share; unchanged on an abstract mesh."""
+    if not isinstance(mesh, Mesh):
+        return plan
+    upd = {}
+    for f in dataclasses.fields(plan):
+        v = getattr(plan, f.name)
+        if isinstance(v, jax.Array) or (
+                isinstance(v, tuple) and v
+                and all(isinstance(a, jax.Array) for a in v)):
+            upd[f.name] = place_blocks(v, mesh, axis)
+    return dataclasses.replace(plan, **upd)
+
+
 def make_dist_spmv(plan: DistPlan, mesh: Mesh, axis: str = "pu",
                    comm: str = "halo",
                    local_format: str = "coo") -> Callable:
-    """Returns jit'd y = A @ x on (k, B) block-major vectors.
+    """Returns jit'd y = A @ x on (k, B) block-major vectors.  The plan's
+    arrays are placed one block per device and bound as the program's
+    first argument — operands, never compiled-in constants.
 
     ``comm='halo'`` (default) overlaps the interior matvec with the
     edge-colored ppermute rounds; ``comm='halo_seq'`` is the sequential
@@ -1448,17 +1478,32 @@ def make_dist_spmv(plan: DistPlan, mesh: Mesh, axis: str = "pu",
                    in_specs=(spec,) * (len(consts) + 1), out_specs=spec)
 
     @jax.jit
-    def spmv(x):
+    def spmv(consts, x):
         return fn(*consts, x)
 
-    return spmv
+    return functools.partial(spmv, place_blocks(consts, mesh, axis))
 
 
 def make_dist_cg(plan: DistPlan, mesh: Mesh, axis: str = "pu",
                  tol: float = 1e-6, max_iters: int = 500,
                  comm: str = "halo", local_format: str = "coo",
                  precondition: str | None = None) -> Callable:
-    """Whole-CG SPMD program: the while_loop runs inside shard_map; dot
+    """The fused CG of :func:`dist_cg_program` with the plan's arrays
+    placed one block per device and bound: ``solve(b)`` on a (k, B[, nb])
+    block-major right-hand side returns ``(x, residual, iters)``."""
+    solve, consts = dist_cg_program(plan, mesh, axis, tol, max_iters, comm,
+                                    local_format, precondition)
+    return functools.partial(solve, place_blocks(consts, mesh, axis))
+
+
+def dist_cg_program(plan: DistPlan, mesh: Mesh, axis: str = "pu",
+                    tol: float = 1e-6, max_iters: int = 500,
+                    comm: str = "halo", local_format: str = "coo",
+                    precondition: str | None = None):
+    """``(solve, consts)``: the jitted ``solve(consts, b)`` and the plan
+    arrays it takes as operands.
+
+    Whole-CG SPMD program: the while_loop runs inside shard_map; dot
     products are psum-reduced local dots; the matvec comes from
     :func:`_local_matvec_builder` — overlapped halo rounds (``'halo'``),
     the sequential schedule (``'halo_seq'``), or the full-vector
@@ -1525,8 +1570,8 @@ def make_dist_cg(plan: DistPlan, mesh: Mesh, axis: str = "pu",
                    out_specs=(spec, spec, spec))
 
     @jax.jit
-    def solve(b):
-        x, res, it = fn(*all_consts, b)
+    def solve(consts, b):
+        x, res, it = fn(*consts, b)
         return x, res[0], it[0]
 
-    return solve
+    return solve, all_consts

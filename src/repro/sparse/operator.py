@@ -8,7 +8,7 @@ interface so that one ``cg_solve`` and one benchmark harness drive
   * ``coo``            — single-device padded-COO segment-sum (spmv.py);
   * ``bell``           — the Pallas block-ELL TPU kernel
                          (kernels/spmv_bell.py), compiled on TPU and
-                         interpreted elsewhere (backend auto-detection);
+                         interpreted on the CPU backend;
   * ``dist_halo``      — shard_map, edge-colored ppermute halo exchange,
                          *overlapped*: the interior matvec (rows touching
                          no halo slot) is issued before the ppermute
@@ -60,6 +60,7 @@ uses.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Protocol, runtime_checkable
 
 import jax
@@ -68,7 +69,8 @@ import numpy as np
 
 from .cg import CGResult, cg_solve
 from .distributed import (DistPlan, build_plan, build_plan_tree,
-                          make_dist_cg, make_dist_spmv)
+                          make_dist_cg, make_dist_spmv, place_blocks,
+                          shard_plan)
 from .spmv import csr_diagonal, csr_to_padded_coo, spmv_coo
 
 
@@ -92,7 +94,13 @@ class Operator(Protocol):
 # --------------------------------------------------------------------------
 # Single-device backends
 # --------------------------------------------------------------------------
+#
+# Both are pytrees over their device arrays (``n`` and the kernel switch
+# are static), so a jitted solve takes the operator as an argument: the
+# matrix is an operand of the compiled program, never a constant in it.
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["rows", "cols", "vals"], meta_fields=["n"])
 @dataclasses.dataclass
 class CooOperator:
     """Padded-COO segment-sum SpMV (any backend, any sparsity).
@@ -149,31 +157,32 @@ def _as_float(x):
     return x
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["blocks", "cols", "diag_"],
+                   meta_fields=["n"])
 @dataclasses.dataclass
 class BlockEllOperator:
-    """Pallas block-ELL SpMV (TPU-compiled; interpreted off-TPU)."""
+    """Pallas block-ELL SpMV (compiled with Mosaic on TPU; interpreted
+    on the CPU backend)."""
 
     n: int
     blocks: jnp.ndarray
     cols: jnp.ndarray
-    interpret: bool | None = None
     diag_: jnp.ndarray | None = None
 
     @classmethod
     def from_csr(cls, indptr, indices, data, bm: int = 8, bk: int = 128,
-                 nnzb: int | None = None, interpret: bool | None = None):
+                 nnzb: int | None = None):
         from ..kernels.spmv_bell import csr_to_block_ell
         n = len(indptr) - 1
         blocks, cols, _meta = csr_to_block_ell(indptr, indices, data, n,
                                                bm=bm, bk=bk, nnzb=nnzb)
         return cls(n=n, blocks=jnp.asarray(blocks), cols=jnp.asarray(cols),
-                   interpret=interpret,
                    diag_=jnp.asarray(csr_diagonal(indptr, indices, data)))
 
     def matvec(self, x):
         from ..kernels.spmv_bell import spmv_block_ell
-        return spmv_block_ell(self.blocks, self.cols, x,
-                              interpret=self.interpret)
+        return spmv_block_ell(self.blocks, self.cols, x)
 
     def operand_spec(self, nb: int | None = None):
         """Abstract matvec operand for device-free tracing (the Pallas
@@ -238,6 +247,9 @@ class DistributedOperator:
 
     def __post_init__(self):
         self.n = self.plan.n
+        # placed once, one block per device (build_plan* leave the plan
+        # on the default device)
+        self.plan = shard_plan(self.plan, self.mesh, self.axis)
         self._spmv = make_dist_spmv(self.plan, self.mesh, axis=self.axis,
                                     comm=self.comm,
                                     local_format=self.local_format)
@@ -320,7 +332,8 @@ class DistributedOperator:
         space application: one batched (B, B) matmul per block; ghost rows
         are identity in M^-1 and their residuals exactly zero, so padding
         stays out of the Krylov space."""
-        minv = self.plan.block_jacobi_inv()          # (k, B, B)
+        minv = place_blocks(self.plan.block_jacobi_inv(), self.mesh,
+                            self.axis)                 # (k, B, B)
 
         def apply(r):
             return jnp.einsum("kij,kj->ki", minv, r)
@@ -328,7 +341,8 @@ class DistributedOperator:
         return apply
 
     def scatter(self, x):
-        return jnp.asarray(self.plan.scatter_vec(np.asarray(x)))
+        return place_blocks(self.plan.scatter_vec(np.asarray(x)), self.mesh,
+                            self.axis)
 
     def gather(self, y):
         return self.plan.gather_vec(np.asarray(y))

@@ -2,9 +2,8 @@
 
 Multi-pod data parallelism reduces gradients across pods over the
 (slower) inter-pod links.  XLA inserts that all-reduce implicitly at
-bf16/f32 width.  Here the pod axis is made *manual* (shard_map over
-'pod' only; 'data'/'model' stay auto-partitioned), so the cross-pod
-reduction can be quantized:
+bf16/f32 width.  Here the step runs in a manual shard_map region, so the
+cross-pod reduction can be quantized:
 
   int8 symmetric quantization (per-tensor scale = pmax|g|/127)
   -> int8 all-gather over 'pod' (1 byte/elem on the wire vs 2 for bf16,
@@ -19,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..compat import SUPPORTS_PARTIAL_MANUAL, shard_map
+from ..compat import shard_map
 
 
 def compressed_psum(tree, axis: str, bits: int = 8):
@@ -68,21 +67,14 @@ def podwise_value_and_grad(loss_fn, mesh, batch_specs, *,
         loss = jax.lax.pmean(loss, "pod")
         return loss, g
 
-    # NOTE (§Perf, measured on jax 0.8.2): in_specs on a partial-auto
-    # shard_map can only constrain the manual axis; the measured dry-run
-    # shows the auto ('data'/'model') shardings of params/batch do NOT
-    # survive the boundary (inner-axis all-reduce x5 on qwen1.5 multi-pod)
-    # — so the int8 pod reduction is numerically validated (tests) but
-    # kept OFF by default until the boundary preserves auto shardings
-    # (jax.sharding.Infer rejects Auto-typed meshes in this version).
-    #
-    # Compat: where partial-manual is unsupported (see compat), the program
-    # is fully manual over every mesh axis — the pod-axis wire traffic
-    # (int8 all-gather) is identical, the data/model axes just recompute
-    # redundantly inside each pod.
-    kw = {"axis_names": {"pod"}} if SUPPORTS_PARTIAL_MANUAL else {}
+    # The program is manual over every mesh axis: a pod-only manual
+    # region (data/model left auto) trips an XLA SPMD-partitioner CHECK on
+    # the embedding gather.  The pod-axis wire traffic (int8 all-gather)
+    # is the same; data/model recompute redundantly inside each pod, so
+    # the int8 pod reduction is numerically validated (tests) but kept
+    # OFF by default.
     return shard_map(
         local, mesh=mesh,
         in_specs=(P(), b_specs),
         out_specs=(P(), P()),
-        check_rep=False, **kw)
+        check_rep=False)

@@ -20,11 +20,6 @@ from repro.analysis.__main__ import main
 
 REPO = Path(__file__).resolve().parents[1]
 
-needs_abstract_mesh = pytest.mark.skipif(
-    not compat.HAS_ABSTRACT_MESH,
-    reason="device-free tracing needs jax.sharding.AbstractMesh")
-
-
 @pytest.fixture()
 def offender_dir(tmp_path):
     bad = tmp_path / "mod.py"
@@ -55,7 +50,6 @@ def test_verify_clean_exits_zero(capsys):
     assert "0 failing" in out
 
 
-@needs_abstract_mesh
 def test_trace_clean_exits_zero(capsys):
     assert main(["trace", "--backend", "coo", "--backend", "dist_halo",
                  "--n", "64"]) == 0
@@ -83,7 +77,6 @@ def test_lint_github_format(offender_dir, capsys, monkeypatch):
     assert "::error file=mod.py,line=1::REPRO001:" in out
 
 
-@needs_abstract_mesh
 def test_trace_json_and_artifact(tmp_path, capsys):
     art = tmp_path / "trace_audit.json"
     rc = main(["trace", "--backend", "dist_halo", "--n", "64",
@@ -99,7 +92,6 @@ def test_trace_json_and_artifact(tmp_path, capsys):
     assert len(cost["comm_payload_bytes_lvl"]) == 1
 
 
-@needs_abstract_mesh
 def test_trace_github_format_on_failure(capsys, monkeypatch):
     """Non-file diagnostics still come out as ::error annotations.  A
     trace failure is simulated by auditing a mutated schedule through the
@@ -135,7 +127,6 @@ def test_subprocess_exit_code_contract(offender_dir):
     assert good.returncode == 0, good.stderr + good.stdout
 
 
-@needs_abstract_mesh
 def test_subprocess_trace_smoke(tmp_path):
     art = tmp_path / "audit.json"
     res = _run_cli(["trace", "--backend", "coo", "--backend", "dist_hier",

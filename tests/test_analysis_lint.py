@@ -65,7 +65,7 @@ def test_repro001_plain_import_and_attribute(tmp_path):
 
 def test_repro001_shard_map_import(tmp_path):
     rep = _lint_snippet(tmp_path, """\
-        from jax.experimental.shard_map import shard_map
+        from jax.experimental import shard_map
     """)
     assert ("REPRO001", 1) in _codes_lines(rep)
 

@@ -29,9 +29,6 @@ from repro.sparse.generators import GENERATORS, grid
 from repro.sparse.graph import laplacian_csr
 from repro.sparse.operator import _HIER_BACKENDS, BACKENDS, make_operator
 
-pytestmark = pytest.mark.skipif(
-    not compat.HAS_ABSTRACT_MESH,
-    reason="device-free tracing needs jax.sharding.AbstractMesh")
 
 
 def _system(n=144, seed=0, generator="grid_2d"):
@@ -254,7 +251,7 @@ def test_trace004_injected_bf16_roundtrip():
 # --------------------------------------------------------------- TRACE005
 
 def test_trace005_f64_leak_under_x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * np.float64(2.0))(
             jax.ShapeDtypeStruct((8,), np.float32))
     rep = audit_jaxpr(closed, base_dtype=np.float32)
@@ -367,6 +364,8 @@ def test_cost_is_roofline_consumable():
     assert cost.hbm_bytes_per_iter > 0
     # the fused CG stages its dot-product psums: all-reduce bytes appear
     assert cost.collectives().get("all-reduce", 0) > 0
+    with pytest.raises(ValueError, match="no published peaks"):
+        static_roofline(cost, device_kind="cpu")
 
 
 def test_cg_cost_separates_loop_body():

@@ -70,3 +70,19 @@ def test_tracked_baselines_parse_and_self_diff_clean(path):
     assert isinstance(payload, dict) and payload
     # identical payloads never regress against themselves
     assert diff_payloads(payload, payload) == ([], [])
+
+
+@pytest.mark.parametrize("ok", [True, False])
+def test_bench_child_failure_exits_nonzero(ok, capsys):
+    """A bench child that fails stops the bench (nonzero exit); it is
+    never recorded as an ``"error"`` result row."""
+    from benchmarks.common import run_child
+
+    script = ('print(\'{"x": 1}\')' if ok
+              else 'import sys; sys.exit("child broke")')
+    if ok:
+        assert run_child(["-c", script], timeout=60) == {"x": 1}
+    else:
+        with pytest.raises(SystemExit, match="exit code 1"):
+            run_child(["-c", script], timeout=60)
+        assert "child broke" in capsys.readouterr().err

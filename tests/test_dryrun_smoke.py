@@ -14,11 +14,12 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax
     import numpy as np
+    from repro.compat import make_mesh
     from repro.configs.registry import get_config
     from repro.launch.dryrun import _lower
     from repro.launch.roofline import analyze_compiled
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     out = {}
     for arch, mode, B, S in [
         ("qwen1.5-0.5b", "train", 4, 64),
@@ -35,7 +36,7 @@ SCRIPT = textwrap.dedent("""
             "coll": sum(rec["collectives"].values()),
         }
     # multi-pod-shaped mesh too
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("qwen1.5-0.5b", smoke=True)
     _, compiled = _lower(cfg, "train", 8, 64, mesh3)
     out["multipod"] = {"ok": True}

@@ -13,14 +13,14 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.models.config import ModelConfig
     from repro.models import transformer
-    from repro.compat import use_mesh
+    from repro.compat import make_mesh, use_mesh
     from repro.models.steps import make_train_step, input_specs
     from repro.train.optimizer import AdamWConfig, init_opt_state
 
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
                       n_heads=4, n_kv_heads=4, d_ff=64, vocab=256,
                       dtype="float32")
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     B, S = 8, 16
     with use_mesh(mesh):
         params, _ = transformer.init_model(jax.random.PRNGKey(0), cfg,

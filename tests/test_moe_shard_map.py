@@ -11,12 +11,12 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.compat import use_mesh
+    from repro.compat import make_mesh, use_mesh
     from repro.models.common import ParamCollector
     from repro.models.mlp import init_moe, moe_forward
 
     B, S, D, E, K, F = 4, 16, 32, 8, 2, 64
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     col = ParamCollector(jax.random.PRNGKey(0), dtype=jnp.float32)
     p, _ = init_moe(col, D, E, F)
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, D), jnp.float32)

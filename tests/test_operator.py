@@ -116,9 +116,8 @@ def test_jacobi_requires_operator():
 # The dist_hier rows run on the two-level (pods=2, k=8) mesh from
 # make_test_mesh(8, pods=2); the dist_tree3 rows on the depth-3
 # (2, 2, 2) ("pod", "host", "pu") mesh from make_test_mesh(8,
-# fanouts=(2, 2, 2)) — the ISSUE 5 acceptance configuration, run in
-# both CI matrix jobs (latest + JAX 0.4.37) so the compat shims see the
-# suffix-combined-axes ppermutes.
+# fanouts=(2, 2, 2)) — the depth-3 configuration, whose ppermutes run
+# over suffix-combined axes.
 
 CROSS_BACKENDS = ("coo", "coo+jacobi", "bell", "bell+jacobi",
                   "dist_halo", "dist_halo+jacobi",

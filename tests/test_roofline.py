@@ -1,6 +1,7 @@
 """Roofline extraction: HLO collective parser + term math."""
 import pytest
 
+from repro.launch.mesh import peaks
 from repro.launch.roofline import (collective_bytes, roofline_terms,
                                    _shape_bytes)
 
@@ -40,9 +41,11 @@ def test_dot_not_counted():
 
 
 def test_roofline_terms():
-    r = roofline_terms(197e12, 819e9, {"all-gather": 50e9, "all-reduce": 0,
-                                       "reduce-scatter": 0, "all-to-all": 0,
-                                       "collective-permute": 0})
+    pk = peaks("TPU v5 lite")
+    r = roofline_terms(pk["peak_flops"], pk["hbm_bw"],
+                       {"all-gather": pk["ici_bw"], "all-reduce": 0,
+                        "reduce-scatter": 0, "all-to-all": 0,
+                        "collective-permute": 0})
     assert abs(r["compute_s"] - 1.0) < 1e-9
     assert abs(r["memory_s"] - 1.0) < 1e-9
     assert abs(r["collective_s"] - 1.0) < 1e-9
@@ -50,7 +53,7 @@ def test_roofline_terms():
 
 
 def test_allreduce_double_counted():
-    r = roofline_terms(0, 0, {"all-gather": 0, "all-reduce": 50e9,
+    r = roofline_terms(0, 0, {"all-gather": 0, "all-reduce": 200e9,
                               "reduce-scatter": 0, "all-to-all": 0,
                               "collective-permute": 0})
     assert abs(r["collective_s"] - 2.0) < 1e-9
@@ -61,3 +64,8 @@ def test_dominant_label():
                                    "reduce-scatter": 0, "all-to-all": 0,
                                    "collective-permute": 0})
     assert r["dominant"] == "compute_s"
+
+
+def test_unknown_device_kind_has_no_peaks():
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(1.0, 1.0, {}, device_kind="cpu")

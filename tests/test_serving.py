@@ -352,7 +352,7 @@ def test_eviction_purges_update_state():
     assert resp.fingerprint not in svc._csr
     assert resp.fingerprint not in svc._monitors
     assert not any(fp == resp.fingerprint for fp, _ in svc._warm)
-    assert resp.fingerprint not in svc._jit
+    assert not any(fp == resp.fingerprint for fp, _ in svc._cost)
     # every auxiliary table only references live operators
     assert set(svc._csr) == set(svc._ops)
     assert set(svc._monitors) <= set(svc._ops)
