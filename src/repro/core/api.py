@@ -42,6 +42,7 @@ import time
 import numpy as np
 
 from ..sparse.graph import Graph
+from ..spans import span
 from .balanced_kmeans import (partition_balanced_kmeans,
                               partition_hierarchical_kmeans)
 from .block_sizes import target_block_sizes, waterfill
@@ -109,38 +110,35 @@ def _greedy_growing(g: Graph, tw: np.ndarray, seed: int = 0) -> np.ndarray:
     return part
 
 
+REFINED = ("geoRef", "geoHier", "sfcRef", "greedyRef")
+
+
 def _dispatch(g: Graph, method: str, tw: np.ndarray, mems: np.ndarray,
               fanouts: tuple[int, ...], seed: int, eps: float,
               **kw) -> np.ndarray:
     """Stage-2 method dispatch shared by the flat and hierarchical
     pipelines; ``tw``/``mems``/``fanouts`` describe whatever block level
-    is being partitioned (PUs, or pods for the hier top level)."""
-    if method == "geoKM":
+    is being partitioned (PUs, or pods for the hier top level).  The
+    ``REFINED`` methods follow their initial partition with multilevel
+    FM (host span ``refine``)."""
+    if method in ("geoKM", "geoRef"):
         part = partition_balanced_kmeans(g, tw, seed=seed, **kw)
-    elif method == "geoRef":
-        part = partition_balanced_kmeans(g, tw, seed=seed, **kw)
-        part = partition_multilevel_refine(g, part, tw, mems=mems, eps=eps,
-                                           seed=seed)
     elif method == "geoHier":
         part = partition_hierarchical_kmeans(g, tw, fanouts, seed=seed, **kw)
-        part = partition_multilevel_refine(g, part, tw, mems=mems, eps=eps,
-                                           seed=seed)
-    elif method == "sfc":
+    elif method in ("sfc", "sfcRef"):
         part = partition_sfc(g, tw, seed=seed)
     elif method == "rcb":
         part = partition_rcb(g, tw, seed=seed)
     elif method == "rib":
         part = partition_rib(g, tw, seed=seed)
-    elif method == "sfcRef":
-        part = partition_sfc(g, tw, seed=seed)
-        part = partition_multilevel_refine(g, part, tw, mems=mems, eps=eps,
-                                           seed=seed)
     elif method == "greedyRef":
         part = _greedy_growing(g, tw, seed=seed)
-        part = partition_multilevel_refine(g, part, tw, mems=mems, eps=eps,
-                                           seed=seed)
     else:
         raise ValueError(f"unknown method {method!r}")
+    if method in REFINED:
+        with span("refine"):
+            part = partition_multilevel_refine(g, part, tw, mems=mems,
+                                               eps=eps, seed=seed)
     return np.asarray(part, dtype=np.int32)
 
 
@@ -163,26 +161,28 @@ def partition(g: Graph, topo: Topology, method: str = "geoRef",
     PUs of modeled compute + weighted deduplicated receive volume,
     ``core.costmodel.BottleneckCost``); ``"cut"`` (default) is the
     summed lambda-cut pipeline, bit-identical to before the objective
-    became selectable."""
-    if pods is not None:
-        res = partition_hier(g, topo, method, pods=pods, tw=tw, seed=seed,
-                             eps=eps, lam=lam, objective=objective, **kw)
-        return res.part, res.tw
-    if fanouts is not None or tree is not None:
-        res = partition_tree(g, topo, method, fanouts=fanouts, tree=tree,
-                             tw=tw, seed=seed, eps=eps, lams=lams,
-                             objective=objective, **kw)
-        return res.part, res.tw
-    if tw is None:
-        tw = target_block_sizes(g.n, topo)
-    part = _dispatch(g, method, tw, topo.memories, topo.fanouts, seed, eps,
-                     **kw)
-    if objective == "bottleneck":
-        part = refine_partition(g, part, tw, mems=topo.memories, eps=eps,
-                                objective="bottleneck", speeds=topo.speeds)
-    elif objective != "cut":
-        raise ValueError(f"unknown objective {objective!r}")
-    return part, tw
+    became selectable.  The call is the host span ``partition`` (stats
+    ``method``, ``k``)."""
+    with span("partition", method=method, k=topo.k):
+        if pods is not None:
+            res = partition_hier(g, topo, method, pods=pods, tw=tw, seed=seed,
+                                 eps=eps, lam=lam, objective=objective, **kw)
+            return res.part, res.tw
+        if fanouts is not None or tree is not None:
+            res = partition_tree(g, topo, method, fanouts=fanouts, tree=tree,
+                                 tw=tw, seed=seed, eps=eps, lams=lams,
+                                 objective=objective, **kw)
+            return res.part, res.tw
+        if tw is None:
+            tw = target_block_sizes(g.n, topo)
+        part = _dispatch(g, method, tw, topo.memories, topo.fanouts, seed, eps,
+                         **kw)
+        if objective == "bottleneck":
+            part = refine_partition(g, part, tw, mems=topo.memories, eps=eps,
+                                    objective="bottleneck", speeds=topo.speeds)
+        elif objective != "cut":
+            raise ValueError(f"unknown objective {objective!r}")
+        return part, tw
 
 
 @dataclasses.dataclass

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..sparse.graph import Graph
+from ..spans import span
 from .geometry import morton_codes, weighted_split_assignment
 from ..kernels import ops as kops
 
@@ -145,18 +146,24 @@ def partition_balanced_kmeans(g: Graph, tw: np.ndarray, seed: int = 0,
                               iters: int = 30, price_steps: int = 12,
                               exact: bool = True,
                               use_pallas: bool = False) -> np.ndarray:
-    """geoKM: balanced k-means with heterogeneous target weights."""
+    """geoKM: balanced k-means with heterogeneous target weights.  Its
+    phases are the host spans ``kmeans.seed`` (Morton seeding on the
+    host), ``kmeans.loop`` (the device loop until its labels are on the
+    host) and ``kmeans.rebalance`` (the host's exact-size pass)."""
     assert g.coords is not None, "balanced k-means needs coordinates"
     tw = np.asarray(tw, dtype=np.float64)
     coords = np.asarray(g.coords, dtype=np.float32)
-    centers0 = _init_centers(coords, tw)
-    part, centers, _ = _bkm_loop(jnp.asarray(coords), jnp.asarray(centers0),
-                                 jnp.asarray(tw, dtype=jnp.float32),
-                                 iters=iters, price_steps=price_steps,
-                                 use_pallas=use_pallas)
-    part = np.asarray(part, dtype=np.int32).copy()
+    with span("kmeans.seed"):
+        centers0 = _init_centers(coords, tw)
+    with span("kmeans.loop"):
+        part, centers, _ = _bkm_loop(
+            jnp.asarray(coords), jnp.asarray(centers0),
+            jnp.asarray(tw, dtype=jnp.float32), iters=iters,
+            price_steps=price_steps, use_pallas=use_pallas)
+        part = np.asarray(part, dtype=np.int32).copy()
     if exact:
-        part = _exact_rebalance(coords, np.asarray(centers), part, tw)
+        with span("kmeans.rebalance"):
+            part = _exact_rebalance(coords, np.asarray(centers), part, tw)
     return part
 
 

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..spans import span
 from .topology import Topology
 
 
@@ -83,13 +84,16 @@ def target_block_sizes(n: float, topo: Topology,
       topo: the compute topology (leaves only are used).
       integral: if True, round to integers that still sum to n (largest
         remainder method, respecting memory caps).
+
+    The call is the host span ``block_sizes``.
     """
     if not topo.feasible(n):
         raise ValueError(
             f"infeasible: load {n} exceeds total memory {topo.total_memory}")
-    tw = waterfill(n, topo.speeds, topo.memories)
-    if integral:
-        tw = _round_preserving_sum(tw, int(round(n)), topo.memories)
+    with span("block_sizes"):
+        tw = waterfill(n, topo.speeds, topo.memories)
+        if integral:
+            tw = _round_preserving_sum(tw, int(round(n)), topo.memories)
     return tw
 
 

@@ -21,8 +21,17 @@ CG solves against a pool of matrices, ROADMAP's solver-as-a-service item):
     serves every batch width in the class.  Padding columns are
     all-zero, and a zero column is *free* under the masked batched CG:
     ``||b||^2 = 0`` keeps it inactive from iteration 0.
-  * **counters** — :class:`ServeStats` tracks operator/bucket hits and
-    misses, evictions, and real vs padded columns (padding waste).
+  * **counters and spans** — :class:`ServeStats` tracks operator/bucket
+    hits and misses, evictions, and real vs padded columns (padding
+    waste).  Under a profiler session each solve records the host span
+    ``repro.serve.solve`` (stats ``request``, ``width``, ``bucket``)
+    holding one span per phase, in order: ``serve.admit`` (the operator
+    cache; ``plan.build`` inside it on a miss), ``serve.pad``,
+    ``serve.scatter`` (host layout and host-to-device copy),
+    ``serve.dispatch`` (the call into the compiled program; long only
+    when it traces or compiles), ``serve.wait`` (the device's CG) and
+    ``serve.gather`` (device-to-host copy, host permutation, padding
+    stripped).
   * **streaming updates** — :meth:`SolverService.update_matrix` applies an
     :class:`repro.sparse.replan.EdgeDelta` to a cached matrix: the plan is
     patched in O(delta) when it carries a replan cache, the old
@@ -55,6 +64,7 @@ from ..sparse.cg import CGResult
 from ..sparse.graph import structure_graph
 from ..sparse.replan import (EdgeDelta, apply_delta_csr, apply_edge_delta,
                              migrate_state)
+from ..spans import span
 
 
 # --------------------------------------------------------------------------
@@ -199,8 +209,9 @@ class SolverService:
             self.stats.operator_hits += 1
             return fp, op, True
         self.stats.operator_misses += 1
-        op = make_operator(indptr, indices, data, self.backend,
-                           **self.op_kw)
+        with span("plan.build"):
+            op = make_operator(indptr, indices, data, self.backend,
+                               **self.op_kw)
         self._install(fp, op, (np.asarray(indptr), np.asarray(indices),
                                np.asarray(data)))
         return fp, op, False
@@ -365,29 +376,37 @@ class SolverService:
               fingerprint: str | None = None) -> SolveResponse:
         """Serve one request: admit ``b`` ((n,) or (n, nb)) into its size
         class, resolve the operator through the cache, run the batched
-        masked CG, strip the padding columns."""
+        masked CG, strip the padding columns.  Each phase is a host span
+        under ``serve.solve`` (module docstring)."""
         b = np.asarray(b)
         single = b.ndim == 1
         bcols = b[:, None] if single else b
         nb = bcols.shape[1]
         bucket = self.bucket_for(nb)
-        fp, op, hit = self.operator_for(indptr, indices, data, fingerprint)
-        warm = (fp, bucket) in self._warm
-        if warm:
-            self.stats.bucket_hits += 1
-        else:
-            self.stats.bucket_misses += 1
-            self._warm.add((fp, bucket))
-        self.stats.real_cols += nb
-        self.stats.padded_cols += bucket - nb
-        self.stats.solves += 1
-        if bucket > nb:
-            pad = np.zeros((bcols.shape[0], bucket - nb), bcols.dtype)
-            bcols = np.concatenate([bcols, pad], axis=1)
-        res = self._run(op, bcols)
-        x = op.gather(res.x)[:, :nb]
-        iters = np.asarray(res.iters)[:nb]
-        residual = np.asarray(res.residual)[:nb]
+        with span("serve.solve", request=self.stats.solves + 1, width=nb,
+                  bucket=bucket):
+            with span("serve.admit"):
+                fp, op, hit = self.operator_for(indptr, indices, data,
+                                                fingerprint)
+            warm = (fp, bucket) in self._warm
+            if warm:
+                self.stats.bucket_hits += 1
+            else:
+                self.stats.bucket_misses += 1
+                self._warm.add((fp, bucket))
+            self.stats.real_cols += nb
+            self.stats.padded_cols += bucket - nb
+            self.stats.solves += 1
+            with span("serve.pad"):
+                if bucket > nb:
+                    pad = np.zeros((bcols.shape[0], bucket - nb),
+                                   bcols.dtype)
+                    bcols = np.concatenate([bcols, pad], axis=1)
+            res = self._run(op, bcols)
+            with span("serve.gather"):
+                x = op.gather(res.x)[:, :nb]
+                iters = np.asarray(res.iters)[:nb]
+                residual = np.asarray(res.residual)[:nb]
         if single:
             x, iters, residual = x[:, 0], iters[0], residual[0]
         return SolveResponse(x=x, iters=iters, residual=residual,
@@ -395,11 +414,21 @@ class SolverService:
                              warm=warm)
 
     def _run(self, op, bcols) -> CGResult:
-        if hasattr(op, "solve"):        # fused distributed program (its
-            # own per-(tol, max_iters, precondition) trace cache)
-            return op.solve(bcols, tol=self.tol, max_iters=self.max_iters,
-                            precondition=self.precondition)
-        return self._solve(op, op.scatter(bcols))
+        """Scatter ``bcols`` into operator space, run CG and wait for it:
+        the fused distributed program where the operator has one (its own
+        per-(tol, max_iters, precondition) trace cache), else the
+        service's jitted batched CG with the operator as an argument."""
+        with span("serve.scatter"):
+            b = op.scatter(bcols)
+        with span("serve.dispatch"):
+            if hasattr(op, "fused_solver"):
+                x, residual, iters = op.fused_solver(
+                    self.tol, self.max_iters, self.precondition)(b)
+                res = CGResult(x=x, iters=iters, residual=residual)
+            else:
+                res = self._solve(op, b)
+        with span("serve.wait"):
+            return jax.block_until_ready(res)
 
 
 def _solver_traffic(args) -> None:
