@@ -26,7 +26,8 @@ CG solves against a pool of matrices, ROADMAP's solver-as-a-service item):
     waste).  Under a profiler session each solve records the host span
     ``repro.serve.solve`` (stats ``request``, ``width``, ``bucket``)
     holding one span per phase, in order: ``serve.admit`` (the operator
-    cache; ``plan.build`` inside it on a miss), ``serve.pad``,
+    cache; ``plan.build`` inside it on a miss, with the ``coo`` layout's
+    ``groups`` and ``pad_share`` as stats), ``serve.pad``,
     ``serve.scatter`` (host layout and host-to-device copy),
     ``serve.dispatch`` (the call into the compiled program; long only
     when it traces or compiles), ``serve.wait`` (the device's CG) and
@@ -59,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.replan_policy import DriftDecision, DriftMonitor, DriftPolicy
-from ..sparse import cg_solve, make_operator
+from ..sparse import CooOperator, cg_solve, make_operator
 from ..sparse.cg import CGResult
 from ..sparse.graph import structure_graph
 from ..sparse.replan import (EdgeDelta, apply_delta_csr, apply_edge_delta,
@@ -209,9 +210,12 @@ class SolverService:
             self.stats.operator_hits += 1
             return fp, op, True
         self.stats.operator_misses += 1
-        with span("plan.build"):
+        with span("plan.build") as build:
             op = make_operator(indptr, indices, data, self.backend,
                                **self.op_kw)
+            if isinstance(op, CooOperator):
+                build.set_metadata(groups=op.groups,
+                                   pad_share=op.pad_share)
         self._install(fp, op, (np.asarray(indptr), np.asarray(indices),
                                np.asarray(data)))
         return fp, op, False
@@ -252,7 +256,8 @@ class SolverService:
         onto the new layout instead of restarting.  Trips without a
         ``repartition`` callable are recorded (``stats.drift_trips``,
         ``response.drift``) but still served by patching — the frozen
-        partition is all there is.
+        partition is all there is.  A single-device operator rebuilt on
+        the mutated matrix takes ``state`` to its own row order.
 
         The old fingerprint is fully retired: a subsequent solve against
         the *unmutated* matrix is an operator miss, never a stale hit.
@@ -311,6 +316,11 @@ class SolverService:
                     and new_plan is not None:
                 moved = migrate_state(plan, new_plan, *out_state)
                 out_state = moved if isinstance(moved, tuple) else (moved,)
+            elif out_state is not None and plan is None:
+                # single-device operators order their rows by the matrix
+                # (``coo`` by row length): back to its order, then on
+                out_state = tuple(new_op.scatter(op.gather(s))
+                                  for s in out_state)
             if monitor is not None:
                 new_cache = getattr(new_plan, "_replan", None)
                 monitor.reset(

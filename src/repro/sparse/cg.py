@@ -3,7 +3,7 @@ systems derived from the graph's Laplacian') — JAX, lax.while_loop.
 
 The operator is passed either as a bare matvec closure or as an
 ``operator.Operator`` (anything with ``matvec`` / ``dot``), so the same
-solver drives the single-device padded-COO SpMV, the Pallas block-ELL
+solver drives the single-device row-group SpMV, the Pallas block-ELL
 kernel, and the distributed shard_map SpMV — one solver, one benchmark
 harness, every backend.
 

@@ -5,7 +5,9 @@ application against matrices distributed by different partitioners; this
 module is the code shape of that idea: a backend-agnostic linear-operator
 interface so that one ``cg_solve`` and one benchmark harness drive
 
-  * ``coo``            — single-device padded-COO segment-sum (spmv.py);
+  * ``coo``            — single-device row groups: rows grouped by length,
+                         each group's products summed over a dense slot
+                         axis, no scatter (spmv.py);
   * ``bell``           — the Pallas block-ELL TPU kernel
                          (kernels/spmv_bell.py), compiled on TPU and
                          interpreted on the CPU backend;
@@ -71,7 +73,7 @@ from .cg import CGResult, cg_solve
 from .distributed import (DistPlan, build_plan, build_plan_tree,
                           make_dist_cg, make_dist_spmv, place_blocks,
                           shard_plan)
-from .spmv import csr_diagonal, csr_to_padded_coo, spmv_coo
+from .spmv import csr_diagonal, csr_to_row_groups, spmv_grouped
 
 
 @runtime_checkable
@@ -100,51 +102,82 @@ class Operator(Protocol):
 # matrix is an operand of the compiled program, never a constant in it.
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=["rows", "cols", "vals"], meta_fields=["n"])
+                   data_fields=["cols", "vals"], meta_fields=["n"])
 @dataclasses.dataclass
 class CooOperator:
-    """Padded-COO segment-sum SpMV (any backend, any sparsity).
+    """Row-group SpMV (any backend, any sparsity; ``spmv.py``).
 
-    ``batch_native``: the scatter-add matvec carries a trailing RHS-batch
-    axis through natively, so the batched CG path needs no vmap."""
+    Rows are stable-sorted by length and cut into groups of one length
+    (``spmv.row_groups``); each group's ``cols``/``vals`` are slot-major
+    ``(L, n_L)`` arrays, and the matvec sums each group's products over
+    its slot axis: a gather and dense row sums, no scatter.  Operator
+    space is that row order, A' = P A P^T: ``scatter`` applies ``perm``
+    on the host before the copy to the device and ``gather`` undoes it
+    after the copy back.  ``perm``, ``inv`` and ``pad_share`` (padded
+    slots over stored entries) stay on the host, out of the pytree
+    (``init=False``); ``perm`` is None for arrays already in operator
+    order.
+
+    ``batch_native``: the matvec carries a trailing RHS-batch axis through
+    natively, so the batched CG path needs no vmap."""
 
     n: int
-    rows: jnp.ndarray
-    cols: jnp.ndarray
-    vals: jnp.ndarray
+    cols: tuple
+    vals: tuple
+    perm: np.ndarray | None = dataclasses.field(default=None, init=False)
+    inv: np.ndarray | None = dataclasses.field(default=None, init=False)
+    pad_share: float = dataclasses.field(default=0.0, init=False)
 
     batch_native = True
 
     @classmethod
-    def from_csr(cls, indptr, indices, data, nnz_pad: int | None = None):
-        rows, cols, vals = csr_to_padded_coo(indptr, indices, data,
-                                             nnz_pad=nnz_pad)
-        return cls(n=len(indptr) - 1, rows=jnp.asarray(rows),
-                   cols=jnp.asarray(cols), vals=jnp.asarray(vals))
+    def from_csr(cls, indptr, indices, data):
+        perm, inv, cols, vals = csr_to_row_groups(indptr, indices, data)
+        op = cls(n=len(perm), cols=tuple(map(jnp.asarray, cols)),
+                 vals=tuple(map(jnp.asarray, vals)))
+        op.perm, op.inv = perm, inv
+        op.pad_share = sum(c.size for c in cols) / max(len(indices), 1) - 1
+        return op
+
+    @property
+    def groups(self) -> int:
+        return len(self.cols)
+
+    @property
+    def dtype(self):
+        return self.vals[0].dtype
 
     def matvec(self, x):
-        return spmv_coo(self.rows, self.cols, self.vals, x, n=self.n)
+        return spmv_grouped(self.cols, self.vals, x)
 
     def operand_spec(self, nb: int | None = None):
         """``ShapeDtypeStruct`` of the matvec operand — the abstract input
         the trace auditor (``repro.analysis.trace``) feeds to
         ``jax.make_jaxpr``; ``nb`` adds the trailing RHS-batch axis."""
         shape = (self.n,) if nb is None else (self.n, nb)
-        return jax.ShapeDtypeStruct(shape, self.vals.dtype)
+        return jax.ShapeDtypeStruct(shape, self.dtype)
 
     def dot(self, u, v):
         return jnp.vdot(u, v)
 
     def diag(self):
-        """On-device diagonal extraction from the padded-COO triples."""
-        on_diag = jnp.where(self.rows == self.cols, self.vals, 0.0)
-        return jnp.zeros(self.n, self.vals.dtype).at[self.rows].add(on_diag)
+        """On-device diagonal in operator order: each group's slots whose
+        column is their own row, summed (padded slots hold 0)."""
+        out, start = [], 0
+        for c, v in zip(self.cols, self.vals):
+            row = start + jnp.arange(c.shape[1], dtype=c.dtype)
+            out.append(jnp.sum(jnp.where(c == row, v, 0), axis=0))
+            start += c.shape[1]
+        return jnp.concatenate(out)
 
     def scatter(self, x):
-        return jnp.asarray(_as_float(x))
+        x = _as_float(x)
+        return jnp.asarray(x if self.perm is None
+                           else np.take(x, self.perm, axis=0))
 
     def gather(self, y):
-        return np.asarray(y)
+        y = np.asarray(y)
+        return y if self.inv is None else np.take(y, self.inv, axis=0)
 
 
 def _as_float(x):

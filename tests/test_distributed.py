@@ -4,7 +4,7 @@ host devices (the main pytest process must keep the default 1 device).
 Exercises the Operator protocol end-to-end: dist_halo and dist_allgather
 backends against the scipy oracle, the fused whole-CG shard_map program,
 the generic cg_solve driving the distributed operator, and cross-backend
-agreement with the single-device padded-COO operator."""
+agreement with the single-device ``coo`` (row-group) operator."""
 import json
 import subprocess
 import sys

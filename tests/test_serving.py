@@ -148,7 +148,7 @@ def test_served_batch_matches_sequential_and_scipy():
     op = CooOperator.from_csr(indptr, indices, data)
     for j, col in enumerate((hard, easy, zero)):
         seq = cg_solve(op, op.scatter(col), tol=1e-7, max_iters=1000)
-        xs = np.asarray(seq.x)
+        xs = op.gather(seq.x)
         scale = max(float(np.abs(xs).max()), 1.0)
         assert np.abs(resp.x[:, j] - xs).max() / scale < 1e-5
         assert abs(int(resp.iters[j]) - int(seq.iters)) <= 2
@@ -197,7 +197,7 @@ def test_operator_preserves_float32_and_promotes_ints():
     indptr, indices, data = _system(6)
     n = len(indptr) - 1
     op = CooOperator.from_csr(indptr, indices, data)
-    assert op.vals.dtype == np.float32
+    assert op.dtype == np.float32
     assert np.asarray(op.diag()).dtype == np.float32
     x = np.ones(n, np.float32)
     assert np.asarray(op.matvec(op.scatter(x))).dtype == np.float32
@@ -206,7 +206,7 @@ def test_operator_preserves_float32_and_promotes_ints():
     # integer values promote to f32 rather than staying int
     op_i = CooOperator.from_csr(indptr, indices,
                                 np.ones_like(data, dtype=np.int32))
-    assert op_i.vals.dtype == np.float32
+    assert op_i.dtype == np.float32
 
 
 F64_SCRIPT = textwrap.dedent("""
@@ -228,14 +228,14 @@ F64_SCRIPT = textwrap.dedent("""
 
     op = CooOperator.from_csr(indptr, indices, data64)
     res = cg_solve(op, op.scatter(b), tol=1e-12, max_iters=2000)
-    x64 = np.asarray(res.x)
+    x64 = op.gather(res.x)
     dense = sp.linalg.spsolve(A, b)
     rel64 = float(np.abs(x64 - dense).max() / np.abs(dense).max())
 
     op32 = CooOperator.from_csr(indptr, indices, data)
     res32 = cg_solve(op32, op32.scatter(b.astype(np.float32)),
                      tol=1e-6, max_iters=2000)
-    rel32 = float(np.abs(np.asarray(res32.x) - dense).max()
+    rel32 = float(np.abs(op32.gather(res32.x) - dense).max()
                   / np.abs(dense).max())
     print(json.dumps({"dtype": str(x64.dtype), "rel64": rel64,
                       "dtype32": str(np.asarray(res32.x).dtype),
@@ -301,6 +301,27 @@ def test_update_matrix_moves_fingerprint():
     ref = sp.linalg.spsolve(A2.astype(np.float64), b.astype(np.float64))
     assert np.abs(np.asarray(r_new.x) - ref).max() \
         / np.abs(ref).max() < 1e-4
+
+
+def test_update_matrix_moves_coo_state_to_the_new_row_order():
+    """A rebuilt ``coo`` operator orders its rows by the mutated matrix's
+    row lengths: operator-space state comes back in the new order."""
+    from repro.sparse.replan import EdgeDelta
+
+    indptr, indices, data = _system(8)
+    n = len(indptr) - 1
+    b = np.random.default_rng(5).normal(size=n).astype(np.float32)
+    svc = SolverService(max_iters=400, tol=1e-7)
+    r0 = svc.solve(indptr, indices, data, b)
+    op = svc._ops[r0.fingerprint]
+    # two new long-range edges lengthen rows 0 and n - 1
+    delta = EdgeDelta(n, set_rows=[0, n - 1], set_cols=[n - 1, 0],
+                      set_vals=[-0.5, -0.5])
+    resp = svc.update_matrix(r0.fingerprint, delta,
+                             state=(op.scatter(b),))
+    new_op = svc._ops[resp.fingerprint]
+    assert not np.array_equal(new_op.perm, op.perm)
+    np.testing.assert_array_equal(new_op.gather(resp.state[0]), b)
 
 
 def test_update_matrix_unknown_or_evicted_fingerprint_raises():

@@ -30,9 +30,10 @@ from repro.sparse.distributed import build_plan, dist_cg_program
 from repro.sparse.generators import grid
 from repro.sparse.graph import laplacian_csr
 from repro.sparse.operator import CooOperator
+from repro.sparse.spmv import row_groups
+from test_spmv_grouped import DELAUNAY_N20_ROW_LENGTHS
 
 N = 1 << 20                   # delaunay_n20
-NNZ = 7 * N                   # Delaunay: ~3n edges, symmetric, + diagonal
 HBM = 16e9
 
 
@@ -98,18 +99,26 @@ def test_geokm_loop_compiles_at_n20(one_chip):
 
 @pytest.mark.parametrize("nb", [1, 16])
 def test_batched_coo_cg_compiles_at_delaunay_n20(one_chip, nb):
-    """The service's batched CG with the operator as an argument: the
-    matrix must be an operand, not a constant baked into the program."""
-    op = CooOperator(n=N, rows=_sds((NNZ,), jnp.int32, one_chip),
-                     cols=_sds((NNZ,), jnp.int32, one_chip),
-                     vals=_sds((NNZ,), jnp.float32, one_chip))
+    """The service's batched CG with the operator as an argument, at the
+    row groups of delaunay_n20's row-length histogram: the matrix must be
+    an operand (8 B a stored slot: an int32 column and an f32 value), not
+    a constant baked into the program."""
+    lengths = np.repeat(list(DELAUNAY_N20_ROW_LENGTHS),
+                        list(DELAUNAY_N20_ROW_LENGTHS.values()))
+    _, counts, widths = row_groups(lengths)
+    op = CooOperator(
+        n=N, cols=tuple(_sds((w, c), jnp.int32, one_chip)
+                        for c, w in zip(counts, widths)),
+        vals=tuple(_sds((w, c), jnp.float32, one_chip)
+                   for c, w in zip(counts, widths)))
     solve = jax.jit(functools.partial(cg_solve, tol=1e-6, max_iters=2000,
                                       batched=True))
     compiled = solve.lower(op, _sds((N, nb), jnp.float32,
                                     one_chip)).compile()
     _fits(compiled)
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes >= 12 * NNZ          # matrix = operand
+    slots = sum(c * w for c, w in zip(counts, widths))
+    assert mem.argument_size_in_bytes >= 8 * slots         # matrix = operand
     assert mem.generated_code_size_in_bytes < 64 << 20     # no baked matrix
 
 
