@@ -27,7 +27,9 @@ CG solves against a pool of matrices, ROADMAP's solver-as-a-service item):
     ``repro.serve.solve`` (stats ``request``, ``width``, ``bucket``)
     holding one span per phase, in order: ``serve.admit`` (the operator
     cache; ``plan.build`` inside it on a miss, with the ``coo`` layout's
-    ``groups`` and ``pad_share`` as stats), ``serve.pad``,
+    ``layout``, ``diagonals``, ``groups`` and ``pad_share`` as stats, and
+    ``mg.build`` inside that where the admission brings multigrid
+    levels), ``serve.pad``,
     ``serve.scatter`` (host layout and host-to-device copy),
     ``serve.dispatch`` (the call into the compiled program; long only
     when it traces or compiles), ``serve.wait`` (the device's CG) and
@@ -200,9 +202,13 @@ class SolverService:
         return nb
 
     def operator_for(self, indptr, indices, data,
-                     fingerprint: str | None = None):
+                     fingerprint: str | None = None, levels=None):
         """``(fingerprint, operator, hit)`` with LRU admission: a cached
-        matrix skips plan construction / format conversion entirely."""
+        matrix skips plan construction / format conversion entirely.
+        ``levels`` (the coarser levels of a multigrid, ``(indptr, indices,
+        data, f2c)`` each; ``coo`` only) builds the hierarchy that
+        ``precondition='mg'`` runs; the cache stays keyed by the fine
+        matrix."""
         fp = fingerprint or matrix_fingerprint(indptr, indices, data)
         op = self._ops.get(fp)
         if op is not None:
@@ -210,11 +216,15 @@ class SolverService:
             self.stats.operator_hits += 1
             return fp, op, True
         self.stats.operator_misses += 1
+        kw = dict(self.op_kw)
+        if levels is not None:
+            kw["levels"] = levels
         with span("plan.build") as build:
-            op = make_operator(indptr, indices, data, self.backend,
-                               **self.op_kw)
+            op = make_operator(indptr, indices, data, self.backend, **kw)
             if isinstance(op, CooOperator):
-                build.set_metadata(groups=op.groups,
+                build.set_metadata(layout=op.layout,
+                                   diagonals=op.diagonals,
+                                   groups=op.groups,
                                    pad_share=op.pad_share)
         self._install(fp, op, (np.asarray(indptr), np.asarray(indices),
                                np.asarray(data)))
@@ -267,6 +277,10 @@ class SolverService:
             raise KeyError(f"unknown or evicted fingerprint "
                            f"{fingerprint!r}")
         op = self._ops[fingerprint]
+        if getattr(op, "mg", None) is not None:
+            raise ValueError("update_matrix does not patch a multigrid "
+                             "hierarchy; admit the changed matrix with its "
+                             "levels")
         indptr, indices, data = csr
         ip2, ix2, d2 = apply_delta_csr(indptr, indices, data, delta)
         new_fp = matrix_fingerprint(ip2, ix2, d2)

@@ -9,6 +9,8 @@ Public surface:
   * ``operator``   — the Operator protocol unifying every backend behind
     ``make_operator`` + ``cg_solve_global`` (see its module docstring);
   * ``cg``         — the one CG solver all backends share;
+  * ``multigrid``  — HPCG's multigrid preconditioner on the ``coo``
+    operator (``make_operator(..., levels=...)``, ``precondition='mg'``);
   * ``replan``     — O(delta) incremental plan patching for streaming
     graphs (``EdgeDelta`` / ``apply_edge_delta`` / ``migrate_state``).
 """

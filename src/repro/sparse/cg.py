@@ -90,13 +90,21 @@ def _resolve_operator(matvec, dot, precondition):
                     "per-PU blocks (DistributedOperator); "
                     f"{type(op).__name__} has none")
             precondition = bj()
+        elif precondition == "mg":
+            mg = getattr(op, "mg_preconditioner", None)
+            if mg is None:
+                raise ValueError(
+                    "precondition='mg' needs an Operator with a multigrid "
+                    f"hierarchy (CooOperator built with levels=); "
+                    f"{type(op).__name__} has none")
+            precondition = mg()
     else:
         batch_native = bool(getattr(matvec, "batch_native", False))
     if isinstance(precondition, str):
         raise ValueError(f"precondition={precondition!r} needs an Operator "
                          "(jacobi: any backend with diag(); block_jacobi: "
-                         "distributed backends); pass a callable M^-1 "
-                         "instead")
+                         "distributed backends; mg: coo with levels=); "
+                         "pass a callable M^-1 instead")
     return matvec, dot, precondition, batch_native
 
 
@@ -173,9 +181,11 @@ def cg_solve(matvec: Callable[[jnp.ndarray], jnp.ndarray], b: jnp.ndarray,
 
     ``precondition`` is ``None`` (plain CG), a callable ``z = M^-1(r)``,
     or a string — ``'jacobi'`` resolves through the Operator's ``diag()``
-    (every backend carries its diagonal on-device) and ``'block_jacobi'``
+    (every backend carries its diagonal on-device), ``'block_jacobi'``
     through the Operator's ``block_jacobi_preconditioner()`` (per-PU
-    diagonal blocks; distributed backends only).  Convergence is always
+    diagonal blocks; distributed backends only) and ``'mg'`` through its
+    ``mg_preconditioner()`` (HPCG's V-cycle; ``coo`` built with
+    ``levels=``).  Convergence is always
     tested on the *unpreconditioned* residual ||r||^2 <= tol^2 ||b||^2, so
     preconditioning changes the iteration count, never the stop quality.
 

@@ -10,8 +10,11 @@
   * refined_mesh — adaptively refined triangular mesh (refinetrace family):
     start from a coarse Delaunay mesh and refine cells near an attractor
     curve, giving strongly non-uniform density.
+  * stencil27 / stencil27_levels — HPCG's matrix (``GenerateProblem``)
+    and its multigrid hierarchy (``GenerateCoarseProblem``), as CSR.
 
-All generators are deterministic given seed and return Graph with coords.
+The graph generators are deterministic given seed and return Graph with
+coords; the stencil generators return CSR arrays.
 """
 from __future__ import annotations
 
@@ -121,6 +124,74 @@ def refined_mesh(n_coarse: int = 2000, refine_rounds: int = 3,
     edges = _tri_edges(tri.simplices)
     return from_edges(len(pts), edges[:, 0], edges[:, 1], symmetrize=True,
                       coords=pts.astype(np.float32))
+
+
+# the 27 offsets (dz, dy, dx) of the stencil, in the order of their linear
+# offsets, so that each row's columns come out sorted
+_STENCIL27 = [(dz, dy, dx) for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+              for dx in (-1, 0, 1)]
+
+
+def stencil27(nx: int, ny: int, nz: int, seed: int | None = None):
+    """HPCG's ``GenerateProblem`` matrix on an ``nx x ny x nz`` grid as CSR
+    ``(indptr, indices, data)``: row ``(z * ny + y) * nx + x`` (lexicographic
+    ``(z, y, x)``) holds 26 on the diagonal and -1 for each of its up to 26
+    neighbours inside the grid, columns sorted, data float32.
+
+    ``seed`` draws other values on the same pattern for tests: symmetric
+    off-diagonals in [-1.5, -0.5] and a diagonal that exceeds its row's
+    absolute off-diagonal sum by a value in [0.5, 1.5], so the matrix
+    stays symmetric positive definite and no layout can rely on constant
+    values."""
+    n = nx * ny * nz
+    idx = np.arange(n, dtype=np.int64)
+    z, rem = np.divmod(idx, nx * ny)
+    y, x = np.divmod(rem, nx)
+    ok = np.empty((n, 27), bool)
+    cols = np.empty((n, 27), np.int64)
+    for k, (dz, dy, dx) in enumerate(_STENCIL27):
+        ok[:, k] = ((0 <= x + dx) & (x + dx < nx) & (0 <= y + dy)
+                    & (y + dy < ny) & (0 <= z + dz) & (z + dz < nz))
+        cols[:, k] = idx + (dz * ny + dy) * nx + dx
+    if seed is None:
+        vals = np.full((n, 27), -1.0)
+        vals[:, 13] = 26.0
+    else:
+        rng = np.random.default_rng(seed)
+        vals = np.zeros((n, 27))
+        for k in range(13):          # below the diagonal, mirrored above
+            v = -rng.uniform(0.5, 1.5, n)
+            vals[:, k] = v
+            rows = idx[ok[:, k]]
+            vals[cols[rows, k], 26 - k] = v[rows]
+        vals[:, 13] = (np.abs(np.where(ok, vals, 0)).sum(axis=1)
+                       + rng.uniform(0.5, 1.5, n))
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(ok.sum(axis=1), out=indptr[1:])
+    return indptr, cols[ok].astype(np.int32), vals[ok].astype(np.float32)
+
+
+def stencil27_levels(nx: int, ny: int, nz: int, depth: int = 4,
+                     seed: int | None = None):
+    """HPCG's multigrid hierarchy (``GenerateCoarseProblem``): the fine
+    ``stencil27`` matrix and, for each of the ``depth - 1`` coarser levels,
+    ``(indptr, indices, data, f2c)``: ``stencil27`` on the grid halved in
+    every dimension, and ``f2c`` (int32), which sends coarse point
+    ``(i, j, k)`` to fine point ``(2i, 2j, 2k)`` of the level above.
+    ``seed`` draws each level's values as :func:`stencil27` does."""
+    fine = stencil27(nx, ny, nz, seed)
+    levels = []
+    for lvl in range(1, depth):
+        if nx % 2 or ny % 2 or nz % 2:
+            raise ValueError(f"level {lvl}: cannot halve a {nx}x{ny}x{nz} "
+                             f"grid")
+        cx, cy, cz = nx // 2, ny // 2, nz // 2
+        z, y, x = np.unravel_index(np.arange(cx * cy * cz), (cz, cy, cx))
+        f2c = ((2 * z * ny + 2 * y) * nx + 2 * x).astype(np.int32)
+        levels.append(stencil27(cx, cy, cz, None if seed is None
+                                else seed + lvl) + (f2c,))
+        nx, ny, nz = cx, cy, cz
+    return fine, levels
 
 
 GENERATORS = {

@@ -5,9 +5,13 @@ application against matrices distributed by different partitioners; this
 module is the code shape of that idea: a backend-agnostic linear-operator
 interface so that one ``cg_solve`` and one benchmark harness drive
 
-  * ``coo``            — single-device row groups: rows grouped by length,
-                         each group's products summed over a dense slot
-                         axis, no scatter (spmv.py);
+  * ``coo``            — single-device local SpMV in the layout the CSR
+                         calls for: diagonals where few offsets j - i
+                         hold the entries densely, else row groups (rows
+                         grouped by length, each group's products summed
+                         over a dense slot axis, no scatter; spmv.py);
+                         with ``levels=`` it carries HPCG's multigrid
+                         (multigrid.py, ``precondition='mg'``);
   * ``bell``           — the Pallas block-ELL TPU kernel
                          (kernels/spmv_bell.py), compiled on TPU and
                          interpreted on the CPU backend;
@@ -73,7 +77,9 @@ from .cg import CGResult, cg_solve
 from .distributed import (DistPlan, build_plan, build_plan_tree,
                           make_dist_cg, make_dist_spmv, place_blocks,
                           shard_plan)
-from .spmv import csr_diagonal, csr_to_row_groups, spmv_grouped
+from .spmv import (csr_diagonal, csr_to_diagonals, csr_to_row_groups,
+                   diagonal_offsets, diagonal_padding, diagonal_rows,
+                   spmv_diagonals, spmv_grouped)
 
 
 @runtime_checkable
@@ -102,21 +108,35 @@ class Operator(Protocol):
 # matrix is an operand of the compiled program, never a constant in it.
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=["cols", "vals"], meta_fields=["n"])
+                   data_fields=["cols", "vals", "mg"],
+                   meta_fields=["n", "bounds", "offsets"])
 @dataclasses.dataclass
 class CooOperator:
-    """Row-group SpMV (any backend, any sparsity; ``spmv.py``).
+    """The one-chip local SpMV (any sparsity; ``spmv.py``), in the layout
+    the CSR calls for (``spmv.MAX_DIAGONALS`` / ``MIN_FILL``):
 
-    Rows are stable-sorted by length and cut into groups of one length
-    (``spmv.row_groups``); each group's ``cols``/``vals`` are slot-major
-    ``(L, n_L)`` arrays, and the matvec sums each group's products over
-    its slot axis: a gather and dense row sums, no scatter.  Operator
-    space is that row order, A' = P A P^T: ``scatter`` applies ``perm``
-    on the host before the copy to the device and ``gather`` undoes it
-    after the copy back.  ``perm``, ``inv`` and ``pad_share`` (padded
-    slots over stored entries) stay on the host, out of the pytree
-    (``init=False``); ``perm`` is None for arrays already in operator
-    order.
+    * diagonals (``offsets`` non-empty): each row block's values one
+      diagonal at a time, ``vals[b]`` of shape ``(D_b, n_b)``; the matvec
+      adds ``D_b`` products of contiguous slices of ``x``, reading no
+      index.  ``cols`` is empty.
+    * row groups: rows stable-sorted by length and cut into groups of one
+      length (``spmv.row_groups``); each group's ``cols``/``vals`` are
+      slot-major ``(L, n_L)`` arrays, and the matvec sums each group's
+      products over its slot axis: a gather and dense row sums, no
+      scatter.
+
+    ``bounds`` cuts operator space into row blocks (block b is rows
+    ``bounds[b]:bounds[b + 1]``; ``()`` is one block): ``from_csr(...,
+    blocks=labels)`` puts the rows of each label together, as the
+    multigrid's colors need, and each block keeps its rows in either
+    layout.  Operator space is that row order, A' = P A P^T: ``scatter``
+    applies ``perm`` on the host before the copy to the device and
+    ``gather`` undoes it after the copy back.  ``perm``, ``inv`` and
+    ``pad_share`` (padded slots over stored entries) stay on the host,
+    out of the pytree (``init=False``); ``perm`` is None for arrays
+    already in operator order.  ``mg`` is the multigrid hierarchy below
+    this matrix (``multigrid.Hierarchy``) where it was built with
+    ``levels=``, else None.
 
     ``batch_native``: the matvec carries a trailing RHS-batch axis through
     natively, so the batched CG path needs no vmap."""
@@ -124,6 +144,9 @@ class CooOperator:
     n: int
     cols: tuple
     vals: tuple
+    bounds: tuple = ()
+    offsets: tuple = ()
+    mg: object = None
     perm: np.ndarray | None = dataclasses.field(default=None, init=False)
     inv: np.ndarray | None = dataclasses.field(default=None, init=False)
     pad_share: float = dataclasses.field(default=0.0, init=False)
@@ -131,24 +154,108 @@ class CooOperator:
     batch_native = True
 
     @classmethod
-    def from_csr(cls, indptr, indices, data):
-        perm, inv, cols, vals = csr_to_row_groups(indptr, indices, data)
-        op = cls(n=len(perm), cols=tuple(map(jnp.asarray, cols)),
-                 vals=tuple(map(jnp.asarray, vals)))
-        op.perm, op.inv = perm, inv
-        op.pad_share = sum(c.size for c in cols) / max(len(indices), 1) - 1
+    def from_csr(cls, indptr, indices, data, blocks=None, levels=None):
+        """``blocks``: a label per row; rows of one label become one row
+        block, labels in ascending order.  ``levels``: the coarser levels
+        of a multigrid below this matrix, ``(indptr, indices, data, f2c)``
+        each (``multigrid.build``)."""
+        if levels is not None:
+            from .multigrid import build
+            return build((indptr, indices, data), levels, cls)
+        n = len(indptr) - 1
+        order, bounds = None, ()
+        if blocks is not None:
+            order = np.argsort(blocks, kind="stable")
+            counts = np.unique(blocks, return_counts=True)[1]
+            bounds = tuple(int(b) for b in np.concatenate(
+                [[0], np.cumsum(counts)]))
+            indptr, indices, data = permute_csr(indptr, indices, data,
+                                                order)
+        offsets = diagonal_offsets(indptr, indices, bounds)
+        if offsets is not None:
+            vals = csr_to_diagonals(indptr, indices, data, offsets, bounds)
+            op = cls(n=n, cols=(), vals=tuple(map(jnp.asarray, vals)),
+                     bounds=bounds, offsets=offsets)
+            perm, slots = order, sum(v.size for v in vals)
+        else:
+            perm, _, cols, vals = csr_to_row_groups(indptr, indices, data,
+                                                    bounds)
+            op = cls(n=n, cols=tuple(map(jnp.asarray, cols)),
+                     vals=tuple(map(jnp.asarray, vals)), bounds=bounds)
+            perm = perm if order is None else order[perm]
+            slots = sum(c.size for c in cols)
+        if perm is not None and not np.array_equal(perm, np.arange(n)):
+            op.perm = perm
+            op.inv = np.empty_like(perm)
+            op.inv[perm] = np.arange(n)
+        op.pad_share = slots / max(len(indices), 1) - 1
         return op
+
+    @property
+    def layout(self) -> str:
+        return "diagonals" if self.offsets else "row groups"
 
     @property
     def groups(self) -> int:
         return len(self.cols)
 
     @property
+    def diagonals(self) -> int:
+        return sum(len(o) for o in self.offsets)
+
+    @property
     def dtype(self):
         return self.vals[0].dtype
 
+    @property
+    def row_blocks(self) -> tuple:
+        """Bounds of the row blocks, one block when none was asked for."""
+        return self.bounds or (0, self.n)
+
+    @property
+    def pad(self) -> tuple[int, int]:
+        """Zeros ``(lo, hi)`` that :meth:`block_rows` wants around x."""
+        if not self.offsets:
+            return 0, 0
+        return diagonal_padding(self.offsets, self.row_blocks)
+
     def matvec(self, x):
+        if self.offsets:
+            return spmv_diagonals(self.vals, x, offsets=self.offsets,
+                                  bounds=self.row_blocks)
         return spmv_grouped(self.cols, self.vals, x)
+
+    def block_rows(self, b: int, xp):
+        """Rows of row block ``b`` of A @ x, ``xp`` being x padded by
+        :attr:`pad`: they read only that block's values."""
+        start = self.row_blocks[b]
+        if self.offsets:
+            return diagonal_rows(self.offsets[b], start, self.vals[b], xp,
+                                 self.pad[0])
+        tail = (1,) * (xp.ndim - 1)
+        out, first = [], 0
+        for c, v in zip(self.cols, self.vals):
+            if start <= first < self.row_blocks[b + 1]:
+                out.append(jnp.sum(v.reshape(v.shape + tail) * xp[c],
+                                   axis=0))
+            first += c.shape[1]
+        return jnp.concatenate(out, axis=0)
+
+    def mg_preconditioner(self):
+        """z = M^-1 r, one V-cycle of HPCG's ComputeMG over the hierarchy
+        built with ``levels=`` (``multigrid.vcycle``); carries the batch
+        axis natively."""
+        if self.mg is None:
+            raise ValueError("precondition='mg' needs a CooOperator built "
+                             "with levels= (the multigrid hierarchy)")
+        from .multigrid import vcycle
+        ops = (self,) + tuple(self.mg.ops)
+
+        def apply(r):
+            return vcycle(ops, self.mg.dinv, self.mg.f2c, r)
+
+        apply.batch_native = True
+        return apply
 
     def operand_spec(self, nb: int | None = None):
         """``ShapeDtypeStruct`` of the matvec operand — the abstract input
@@ -161,9 +268,15 @@ class CooOperator:
         return jnp.vdot(u, v)
 
     def diag(self):
-        """On-device diagonal in operator order: each group's slots whose
-        column is their own row, summed (padded slots hold 0)."""
+        """On-device diagonal in operator order: the offset-0 diagonal of
+        each row block, or each group's slots whose column is their own
+        row, summed (padded slots hold 0)."""
         out, start = [], 0
+        if self.offsets:
+            for o, v in zip(self.offsets, self.vals):
+                out.append(v[o.index(0)] if 0 in o
+                           else jnp.zeros(v.shape[1], v.dtype))
+            return jnp.concatenate(out)
         for c, v in zip(self.cols, self.vals):
             row = start + jnp.arange(c.shape[1], dtype=c.dtype)
             out.append(jnp.sum(jnp.where(c == row, v, 0), axis=0))
@@ -178,6 +291,21 @@ class CooOperator:
     def gather(self, y):
         y = np.asarray(y)
         return y if self.inv is None else np.take(y, self.inv, axis=0)
+
+
+def permute_csr(indptr, indices, data, perm):
+    """P A P^T as CSR: row i is row ``perm[i]`` of A, with its columns
+    renumbered the same way (left unsorted).  O(nnz)."""
+    indptr = np.asarray(indptr, np.int64)
+    lengths = np.diff(indptr)[perm]
+    new_ptr = np.zeros(len(perm) + 1, np.int64)
+    np.cumsum(lengths, out=new_ptr[1:])
+    src = (np.arange(new_ptr[-1])
+           - np.repeat(new_ptr[:-1] - indptr[perm], lengths))
+    inv = np.empty(len(perm), np.int64)
+    inv[perm] = np.arange(len(perm))
+    return (new_ptr, inv[np.asarray(indices)[src]].astype(np.int32),
+            np.asarray(data)[src])
 
 
 def _as_float(x):

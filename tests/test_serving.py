@@ -305,10 +305,13 @@ def test_update_matrix_moves_fingerprint():
 
 def test_update_matrix_moves_coo_state_to_the_new_row_order():
     """A rebuilt ``coo`` operator orders its rows by the mutated matrix's
-    row lengths: operator-space state comes back in the new order."""
+    row lengths: operator-space state comes back in the new order.  An
+    irregular mesh, which keeps the row groups (a grid's few diagonals
+    would keep its rows in their own order)."""
+    from repro.sparse.generators import rdg
     from repro.sparse.replan import EdgeDelta
 
-    indptr, indices, data = _system(8)
+    indptr, indices, data = laplacian_csr(rdg(64, seed=1), shift=0.05)
     n = len(indptr) - 1
     b = np.random.default_rng(5).normal(size=n).astype(np.float32)
     svc = SolverService(max_iters=400, tol=1e-7)
@@ -320,6 +323,7 @@ def test_update_matrix_moves_coo_state_to_the_new_row_order():
     resp = svc.update_matrix(r0.fingerprint, delta,
                              state=(op.scatter(b),))
     new_op = svc._ops[resp.fingerprint]
+    assert op.layout == new_op.layout == "row groups"
     assert not np.array_equal(new_op.perm, op.perm)
     np.testing.assert_array_equal(new_op.gather(resp.state[0]), b)
 

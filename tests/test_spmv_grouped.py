@@ -185,8 +185,9 @@ def test_matvec_holds_no_scatter(nb):
 
 
 def test_plan_build_span_records_the_layout(tmp_path):
-    """``plan.build`` carries the admitted layout's ``groups`` and
-    ``pad_share`` as stats on the profiler's host plane."""
+    """``plan.build`` carries the admitted layout's ``layout``,
+    ``diagonals``, ``groups`` and ``pad_share`` as stats on the
+    profiler's host plane."""
     from jax.profiler import ProfileData
 
     from repro.launch.serve import SolverService
@@ -203,6 +204,8 @@ def test_plan_build_span_records_the_layout(tmp_path):
     (stats,) = [dict(e.stats) for p in ProfileData.from_file(str(path)).planes
                 for line in p.lines for e in line.events
                 if e.name == "repro.plan.build"]
+    assert stats["layout"] == op.layout == "row groups"
+    assert stats["diagonals"] == op.diagonals == 0
     assert stats["groups"] == op.groups == len(op.cols)
     assert stats["pad_share"] == pytest.approx(op.pad_share)
     assert 0 <= op.pad_share < 0.02
