@@ -100,17 +100,135 @@ def _bkm_loop(coords, centers, tw, iters: int, price_steps: int,
     return part, centers, log_price
 
 
+# n·k up to which the exact-size pass keeps the whole squared-distance table
+D2_TABLE_MAX = 5_000_000
+
+
+def _sqdist(points: np.ndarray, center: np.ndarray) -> np.ndarray:
+    """Squared distances of ``points`` to ``center``, summed over the axes
+    in order: for 2-D and 3-D points, bit for bit what
+    ``((points - center) ** 2).sum(-1)`` gives."""
+    acc = (points[:, 0] - center[0]) ** 2
+    for a in range(1, points.shape[1]):
+        acc += (points[:, a] - center[a]) ** 2
+    return acc
+
+
+def _order_keys(val: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """uint64 keys that sort as the pairs ``(val, row)``: float32 ``val``
+    in the high half, its bits made monotone (-0.0 as 0.0), ``row`` in
+    the low half."""
+    bits = (val + np.float32(0)).view(np.uint32)
+    bits = np.where(bits >> 31, ~bits, bits | np.uint32(1 << 31))
+    return (bits.astype(np.uint64) << np.uint64(32)) | rows.astype(np.uint64)
+
+
+def _greedy_moves(regret: np.ndarray, left: np.ndarray,
+                  n_move: int) -> tuple[np.ndarray, int]:
+    """The cheapest-regret-first walk over a (targets, rows) float32
+    ``regret``, decided a target at a time.
+
+    The walk visits the entries in order of (regret, row, target) and
+    moves a row to a target iff the row has not moved, the target still
+    has ``left`` room and fewer than ``n_move`` have moved.  A pass gives
+    each unmoved row its first open target (the least regret, the lowest
+    target among equals), orders the rows by (regret, row), and accepts
+    the prefix that ends where a target fills or ``n_move`` is reached:
+    up to there the walk takes exactly these entries.  The filled target
+    closes, and only the rows that had chosen it choose again, so a round
+    takes at most one pass per target.  Returns each row's target (-1
+    where it stays) and the passes.
+    """
+    u, m = regret.shape
+    left = left.astype(np.int64)
+    # targets in the smallest int type: their stable argsort is a radix sort
+    best = np.argmin(regret, axis=0).astype(np.min_scalar_type(u - 1))
+    val = regret[best, np.arange(m)]
+    keys = np.sort(_order_keys(val, np.arange(m)))
+    dest = np.full(m, -1, np.int64)
+    passes = 0
+    while True:
+        passes += 1
+        rows = (keys & np.uint64(0xFFFFFFFF)).astype(np.intp)
+        tgt = best[rows]
+        cnt = np.bincount(tgt, minlength=u)
+        fills = np.nonzero((left > 0) & (cnt >= left))[0]
+        stop = min(len(rows), n_move)
+        if len(fills):                  # where each target's room runs out
+            first = np.cumsum(cnt) - cnt
+            at = np.argsort(tgt, kind="stable")[first[fills] + left[fills] - 1]
+            stop = min(stop, int(at.min()) + 1)
+        dest[rows[:stop]] = tgt[:stop]
+        left -= np.bincount(tgt[:stop], minlength=u)
+        n_move -= stop
+        if n_move == 0 or stop == len(rows) or not left.any():
+            return dest, passes
+        rest, stale = keys[stop:], left[tgt[stop:]] == 0
+        again = rows[stop:][stale]
+        open_ = np.nonzero(left > 0)[0]
+        sub = regret[np.ix_(open_, again)]
+        j = np.argmin(sub, axis=0)
+        best[again] = open_[j]
+        val = sub[j, np.arange(len(again))]
+        # timsort merges the two sorted runs
+        keys = np.sort(np.concatenate(
+            [rest[~stale], np.sort(_order_keys(val, again))]), kind="stable")
+
+
 def _exact_rebalance(coords: np.ndarray, centers: np.ndarray,
-                     part: np.ndarray, tw: np.ndarray) -> np.ndarray:
+                     part: np.ndarray, tw: np.ndarray
+                     ) -> tuple[np.ndarray, dict]:
     """Post-pass: enforce sizes exactly (floor(tw) sum-preserving) by moving
     the cheapest vertices out of overloaded blocks to the nearest underloaded
     block.  Keeps compactness: candidates are those with the smallest
-    (d_target^2 - d_own^2) regret."""
+    (d_target^2 - d_own^2) regret.
+
+    A round takes the most overloaded block and moves its surplus by
+    :func:`_greedy_moves`.  ``coords`` and ``centers`` are finite float32,
+    as geoKM holds them.  Returns the labels and the stats ``rounds``,
+    ``passes`` (over all rounds) and ``moved`` (vertices relabelled).
+    """
+    k = len(tw)
+    want = np.round(tw).astype(np.int64)
+    want[np.argmax(want)] += len(part) - want.sum()  # fix rounding drift
+    d2 = np.stack([_sqdist(coords, c) for c in centers]) \
+        if len(coords) * k <= D2_TABLE_MAX else None
+    stats = {"rounds": 0, "passes": 0, "moved": 0}
+    for _ in range(4 * k):
+        sizes = np.bincount(part, minlength=k)
+        over = np.nonzero(sizes > want)[0]
+        under = np.nonzero(sizes < want)[0]
+        if len(over) == 0:
+            break
+        b = over[np.argmax(sizes[over] - want[over])]
+        members = np.nonzero(part == b)[0]
+        if d2 is not None:
+            regret = d2[np.ix_(under, members)] - d2[b, members]
+        else:
+            dm = coords[members]
+            d_own = _sqdist(dm, centers[b])
+            regret = np.stack([_sqdist(dm, centers[t]) for t in under])
+            regret -= d_own
+        dest, passes = _greedy_moves(regret, want[under] - sizes[under],
+                                     int(sizes[b] - want[b]))
+        go = dest >= 0
+        part[members[go]] = under[dest[go]]
+        stats["rounds"] += 1
+        stats["passes"] += passes
+        stats["moved"] += int(go.sum())
+    return part, stats
+
+
+def _exact_rebalance_reference(coords: np.ndarray, centers: np.ndarray,
+                               part: np.ndarray, tw: np.ndarray) -> np.ndarray:
+    """:func:`_exact_rebalance`'s labels by the plain walk: argsort each
+    round's flattened regrets and visit them one entry at a time.  Kept
+    for the tests to compare against."""
     k = len(tw)
     want = np.round(tw).astype(np.int64)
     want[np.argmax(want)] += len(part) - want.sum()  # fix rounding drift
     d2 = ((coords[:, None, :] - centers[None, :, :]) ** 2).sum(-1) \
-        if len(coords) * k <= 5_000_000 else None
+        if len(coords) * k <= D2_TABLE_MAX else None
     for _ in range(4 * k):
         sizes = np.bincount(part, minlength=k)
         over = np.nonzero(sizes > want)[0]
@@ -149,7 +267,8 @@ def partition_balanced_kmeans(g: Graph, tw: np.ndarray, seed: int = 0,
     """geoKM: balanced k-means with heterogeneous target weights.  Its
     phases are the host spans ``kmeans.seed`` (Morton seeding on the
     host), ``kmeans.loop`` (the device loop until its labels are on the
-    host) and ``kmeans.rebalance`` (the host's exact-size pass)."""
+    host) and ``kmeans.rebalance`` (the host's exact-size pass, with its
+    stats ``rounds``, ``passes`` and ``moved``)."""
     assert g.coords is not None, "balanced k-means needs coordinates"
     tw = np.asarray(tw, dtype=np.float64)
     coords = np.asarray(g.coords, dtype=np.float32)
@@ -162,8 +281,10 @@ def partition_balanced_kmeans(g: Graph, tw: np.ndarray, seed: int = 0,
             price_steps=price_steps, use_pallas=use_pallas)
         part = np.asarray(part, dtype=np.int32).copy()
     if exact:
-        with span("kmeans.rebalance"):
-            part = _exact_rebalance(coords, np.asarray(centers), part, tw)
+        with span("kmeans.rebalance") as rebalance:
+            part, stats = _exact_rebalance(coords, np.asarray(centers),
+                                           part, tw)
+            rebalance.set_metadata(**stats)
     return part
 
 
